@@ -141,7 +141,6 @@ def _bench_attn_bwd(quick: bool):
     """A/B the attention backward: recompute-through-ref VJP (the scheme
     this repo shipped before the fused kernels) vs the fused Pallas
     dq/dk/dv backward. Returns {name: {us, flops, bwd_flops}}."""
-    from repro.launch import compat
     from repro.models import attention as attn_lib
 
     b, s, h, kv, hd, w = ((2, 64, 4, 2, 16, 16) if quick
@@ -180,10 +179,10 @@ def _bench_attn_bwd(quick: bool):
     for name, loss in (("recompute", loss_recompute), ("fused", loss_fused)):
         if fwd_flops is None:
             cf = jax.jit(loss).lower(q, k, v).compile()
-            fwd_flops = compat.cost_analysis(cf).get("flops", 0.0)
+            fwd_flops = cf.cost_analysis().get("flops", 0.0)
         g = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
         cg = g.lower(q, k, v).compile()
-        flops = compat.cost_analysis(cg).get("flops", 0.0)
+        flops = cg.cost_analysis().get("flops", 0.0)
         t = time_fn(g, q, k, v, warmup=1, iters=3)
         out[name] = {"us": t, "flops": flops,
                      "bwd_flops": max(flops - fwd_flops, 0.0)}
@@ -200,7 +199,6 @@ def _bench_damped_inverse(quick: bool):
     comparison, the FLOP column documents that NS is pure countable
     matmuls. Returns {name: {us, flops, maxerr...}}."""
     from repro.kernels import dispatch
-    from repro.launch import compat
 
     nb, b = (2, 64) if quick else (4, 128)
     rng = np.random.RandomState(0)
@@ -218,7 +216,7 @@ def _bench_damped_inverse(quick: bool):
     out = {}
     for name, fn in fns.items():
         cf = fn.lower(f, d).compile()
-        flops = compat.cost_analysis(cf).get("flops", 0.0)
+        flops = cf.cost_analysis().get("flops", 0.0)
         out[name] = {"us": time_fn(fn, f, d, warmup=1, iters=3),
                      "flops": flops}
     err = float(jnp.max(jnp.abs(fns["newton_schulz"](f, d)
@@ -296,16 +294,20 @@ def _bench_serve(quick: bool):
 
 
 def _bench_in_subprocess(flag: str, local_fn, quick: bool, what: str):
-    """Run a multi-device A/B body in a SUBPROCESS with 8 virtual CPU
-    devices so the collectives are real multi-device programs — setting the
-    device count in this process would oversubscribe the CPU and skew every
-    other benchmark row's timing (the cross-PR A/B ratios in
-    BENCH_kernels.json must stay comparable). Falls back to an in-process
-    run on whatever devices exist if the subprocess fails."""
+    """Run a multi-device A/B body. On the CPU backend it runs in a
+    SUBPROCESS with 8 virtual CPU devices so the collectives are real
+    multi-device programs — setting the device count in this process would
+    oversubscribe the CPU and skew every other benchmark row's timing (the
+    cross-PR A/B ratios in BENCH_kernels.json must stay comparable). On a
+    TPU this process already holds the chips, and a child could not get
+    them: the body runs here, on the real devices. A failing child fails
+    the benchmark."""
     import json
     import subprocess
     import sys
 
+    if jax.default_backend() == "tpu":
+        return local_fn(quick)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
@@ -313,16 +315,16 @@ def _bench_in_subprocess(flag: str, local_fn, quick: bool, what: str):
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (os.path.join(root, "src"), root,
                     os.environ.get("PYTHONPATH", "")) if p)
-    try:
-        proc = subprocess.run(
-            [sys.executable, "-m", "benchmarks.kernels_bench",
-             flag] + (["--quick"] if quick else []),
-            env=env, cwd=root, capture_output=True, text=True, check=True)
-        return json.loads(proc.stdout.splitlines()[-1])
-    except (subprocess.CalledProcessError, ValueError, IndexError) as e:
-        print(f"# {what} A/B subprocess failed ({e}); running in-process on "
-              f"{len(jax.devices())} device(s)", file=sys.stderr)
-        return local_fn(quick)
+    # the child is CPU-only: it must never reach for an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
+    proc = subprocess.run(
+        [sys.executable, "-m", "benchmarks.kernels_bench",
+         flag] + (["--quick"] if quick else []),
+        env=env, cwd=root, capture_output=True, text=True)
+    if proc.returncode != 0:
+        raise RuntimeError(f"{what} A/B subprocess failed "
+                           f"(exit {proc.returncode}):\n{proc.stderr}")
+    return json.loads(proc.stdout.splitlines()[-1])
 
 
 def _bench_comm(quick: bool):
@@ -369,7 +371,7 @@ def _bench_overlap_local(quick: bool):
 
     from repro.configs import get_config
     from repro.core.ngd import NGDConfig, SPNGD
-    from repro.launch import compat
+    from repro.launch.mesh import make_mesh
     from repro.launch.train import (make_shardmap_fast_step,
                                     make_shardmap_train_step)
     from repro.models.transformer import DecoderLM
@@ -379,9 +381,9 @@ def _bench_overlap_local(quick: bool):
     reps = 2 if quick else 3
     b, s = (4, 16) if quick else (8, 16)
     if ndev >= 4 and ndev % 2 == 0:
-        mesh = compat.make_mesh((ndev // 2, 2), ("data", "model"))
+        mesh = make_mesh((ndev // 2, 2), ("data", "model"))
     else:                                  # in-process fallback: tiny mesh
-        mesh = compat.make_mesh((ndev, 1), ("data", "model"))
+        mesh = make_mesh((ndev, 1), ("data", "model"))
     dp_n = mesh.shape["data"]
     b = max(b, dp_n)
 
@@ -483,10 +485,10 @@ def _bench_comm_local(quick: bool):
     from jax.sharding import PartitionSpec as P
 
     from repro.comm import FactorReducer, make_comm_config
-    from repro.launch import compat
+    from repro.launch.mesh import make_mesh
 
     ndev = len(jax.devices())
-    mesh = compat.make_mesh((ndev,), ("data",))
+    mesh = make_mesh((ndev,), ("data",))
     nb, b = (2, 32) if quick else (4, 64)
     lead = 2 * ndev                      # scatters over the data axis
     template = {"fam": {
@@ -519,9 +521,10 @@ def _bench_comm_local(quick: bool):
             return red.reduce(jax.tree.map(lambda x: x[0], raw))
 
         in_specs = jax.tree.map(lambda _: P("data"), raw_all)
-        fn = jax.jit(compat.shard_map(
+        fn = jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=(in_specs,),
-            out_specs=red.out_specs(), axis_names={"data"}))
+            out_specs=red.out_specs(), axis_names={"data"},
+            check_vma=False))
         t = time_fn(fn, raw_all, warmup=1, iters=3)
         results[strat] = jax.tree.map(np.asarray, fn(raw_all))
         out[f"comm.reduce_{strat}"] = {
@@ -586,9 +589,9 @@ def _bench_comm_local(quick: bool):
         return red.reduce(jax.tree.map(lambda x: x[0], raw))
 
     in_specs = jax.tree.map(lambda _: P("data"), raw_wire)
-    fn = jax.jit(compat.shard_map(
+    fn = jax.jit(jax.shard_map(
         body_w, mesh=mesh, in_specs=(in_specs,),
-        out_specs=red.out_specs(), axis_names={"data"}))
+        out_specs=red.out_specs(), axis_names={"data"}, check_vma=False))
     t = time_fn(fn, raw_wire, warmup=1, iters=3)
     res = jax.tree.map(np.asarray, fn(raw_wire))
     err = max(float(np.max(np.abs(a - d))) for a, d in zip(
@@ -618,10 +621,10 @@ def _bench_stage4_local(quick: bool):
 
     from repro.comm import FactorReducer, Stage4Inverter, make_comm_config
     from repro.kernels import dispatch
-    from repro.launch import compat
+    from repro.launch.mesh import make_mesh
 
     ndev = len(jax.devices())
-    mesh = compat.make_mesh((ndev,), ("data",))
+    mesh = make_mesh((ndev,), ("data",))
     lead, b = (ndev, 48) if quick else (2 * ndev, 96)
     rng = np.random.RandomState(0)
     q = np.linalg.qr(rng.randn(lead, b, b))[0]
@@ -639,9 +642,9 @@ def _bench_stage4_local(quick: bool):
         # d (lead,) already matches the 3-D stat's batch dims
         return dispatch.damped_inverse(s, d, method="eigh", backend="ref")
 
-    repl = jax.jit(compat.shard_map(
+    repl = jax.jit(jax.shard_map(
         repl_body, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
-        axis_names={"data"}))
+        axis_names={"data"}, check_vma=False))
     shard = jax.jit(functools.partial(inv4.invert, fam="fam", key="a"))
 
     t_repl = time_fn(repl, f, damp, warmup=1, iters=3)

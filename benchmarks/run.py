@@ -61,6 +61,8 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     args, _ = ap.parse_known_args()
 
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     from benchmarks import (convergence, fisher_ablation, kernels_bench,
                             scaling, serve_bench, stale_reduction)
     modules = {

@@ -131,7 +131,6 @@ class Stage4Inverter:
         from jax.sharding import PartitionSpec as P
 
         from repro.kernels import dispatch
-        from repro.launch import compat
         from repro.obs.tracing import STAGE_INVERSE
 
         axes = self.reducer.scatter_axes(stat.shape[0]) \
@@ -171,8 +170,9 @@ class Stage4Inverter:
         out_specs = (P(), {k: P() for k in ("ns_res", "ns_converged",
                                             "owner")}) \
             if return_info else P()
-        sm = compat.shard_map(local, mesh=mesh,
-                              in_specs=(stat_spec, damp_spec),
-                              out_specs=out_specs, axis_names=set(axes))
+        sm = jax.shard_map(local, mesh=mesh,
+                           in_specs=(stat_spec, damp_spec),
+                           out_specs=out_specs, axis_names=set(axes),
+                           check_vma=False)
         with jax.named_scope(f"{STAGE_INVERSE}[sharded:{fam}.{key}]"):
             return sm(stat, damp)

@@ -309,24 +309,36 @@ def tril_indices(b: int) -> tuple[np.ndarray, np.ndarray]:
     return np.tril_indices(b)
 
 
+def _take_bits(x: jax.Array, idx: np.ndarray) -> jax.Array:
+    """Static gather along the last axis that moves BITS, not values: float
+    payloads gather through their same-width unsigned view. A float gather
+    may canonicalize NaN encodings (XLA:CPU widens fp8 before moving it),
+    and packed payloads must round-trip bit for bit."""
+    idx = jnp.asarray(idx)
+    if not jnp.issubdtype(x.dtype, jnp.floating):
+        return jnp.take(x, idx, axis=-1)
+    bits = jnp.dtype(f"uint{8 * x.dtype.itemsize}")
+    u = jax.lax.bitcast_convert_type(x, bits)
+    return jax.lax.bitcast_convert_type(jnp.take(u, idx, axis=-1), x.dtype)
+
+
 def sym_pack(f: jax.Array) -> jax.Array:
     """Pack symmetric (..., b, b) into (..., b(b+1)/2)."""
     b = f.shape[-1]
     i, j = np.tril_indices(b)
-    return f[..., i, j]
+    return _take_bits(f.reshape(f.shape[:-2] + (b * b,)), i * b + j)
 
 
 def sym_unpack(p: jax.Array, b: int) -> jax.Array:
     """Inverse of :func:`sym_pack`. A static GATHER, not a scatter: entry
     (r, c) reads packed position tri(max(r,c)) + min(r,c) — cheaper to
-    lower, and exact for any dtype (incl. fp8 payloads) since no arithmetic
-    touches the values."""
+    lower, and exact for any dtype (incl. fp8 payloads) since only bits
+    move."""
     r = np.arange(b)
     hi = np.maximum(r[:, None], r[None, :])
     lo = np.minimum(r[:, None], r[None, :])
     idx = (hi * (hi + 1)) // 2 + lo                      # (b, b) int
-    f = jnp.take(p, jnp.asarray(idx.reshape(-1)), axis=-1)
-    return f.reshape(p.shape[:-1] + (b, b))
+    return _take_bits(p, idx.reshape(-1)).reshape(p.shape[:-1] + (b, b))
 
 
 # ---------------------------------------------------------------------------

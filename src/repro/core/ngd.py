@@ -284,11 +284,16 @@ class SPNGD:
             entry = {"prev": {}, "prev2": {}, "precond": {}}
             for key, leaf in stats.items():
                 shape = _dense_leaf_shape(leaf)
-                z = self._encode_hist(fam, key,
-                                      jnp.zeros(shape, jnp.float32))
-                entry["prev"][key] = z
+
+                def zero_hist():
+                    return self._encode_hist(fam, key,
+                                             jnp.zeros(shape, jnp.float32))
+
+                # every leaf gets a buffer of its own: the step programs
+                # donate the state, and one buffer cannot be donated twice
+                entry["prev"][key] = zero_hist()
                 if self.cfg.history >= 2:
-                    entry["prev2"][key] = z
+                    entry["prev2"][key] = zero_hist()
                 if key in ("a", "g"):
                     kind = info.spec.a_kind if key == "a" else info.spec.g_kind
                     if kind == "full":
@@ -303,7 +308,8 @@ class SPNGD:
                 # staged buffer: what the NEXT step will activate. Seeding
                 # it from the active init makes step 1 a plain identity-
                 # preconditioned step (the pipeline's one-step warm-up).
-                entry["precond_next"] = dict(entry["precond"])
+                entry["precond_next"] = jax.tree.map(jnp.copy,
+                                                     entry["precond"])
             curv[fam] = entry
         state = {
             "step": jnp.zeros((), jnp.int32),
@@ -549,7 +555,11 @@ class SPNGD:
             u = leaf_update(path_str, g)
             gsq += jnp.sum(jnp.square(g.astype(jnp.float32)))
             usq += jnp.sum(jnp.square(u.astype(jnp.float32)))
-            v = mom * flat_v[path_str] - lr * u.astype(flat_v[path_str].dtype)
+            v_dtype = flat_v[path_str].dtype
+            # strongly typed f32 lr/mom would promote a bf16 velocity: keep
+            # its storage dtype so the donated state buffers alias in place
+            v = (mom * flat_v[path_str] - lr * u.astype(v_dtype)
+                 ).astype(v_dtype)
             w = flat_p[path_str] + v.astype(flat_p[path_str].dtype)
             new_v[path_str] = v
             new_p[path_str] = w
@@ -720,7 +730,8 @@ class SPNGD:
         for fam, entry in state["curv"].items():
             entry = dict(entry)
             if self.cfg.double_buffer and "precond_next" not in entry:
-                entry["precond_next"] = dict(entry["precond"])
+                entry["precond_next"] = jax.tree.map(jnp.copy,
+                                                     entry["precond"])
             if not self.cfg.double_buffer:
                 entry.pop("precond_next", None)
             curv[fam] = entry

@@ -15,7 +15,11 @@ hardware speed. This module owns the decision of *which* implementation runs:
   (matmul-shaped ops gate on their contraction dims — tiny dims cannot fill
   an MXU tile and lose to plain XLA; attention gates on sequence length
   only, being bandwidth- not MXU-bound). On CPU auto always resolves to
-  ref, so it is semantics-preserving for tests.
+  ref, so it is semantics-preserving for tests. Nor does auto pick a kernel
+  where the traced region spans several devices under the compiler's
+  partitioning (a ``jit`` over a multi-device mesh, a ``shard_map`` that
+  leaves a mesh axis automatic): Mosaic kernels are not partitioned
+  automatically, so there only ref can lower.
 
 Every public op here accepts the *blocked* factor layout used by the rest of
 the framework — arrays of shape ``(lead..., nb, b, b)`` with arbitrary
@@ -33,9 +37,11 @@ Register an implementation for an existing op (or a new op name) with
     from repro.kernels import dispatch
     dispatch.register("factor_sum", "pallas", my_faster_impl)
 
-An op resolved to a backend with no registered implementation falls back to
-``"ref"`` (so e.g. ``backend="pallas"`` still trains end-to-end while ops are
-ported one at a time); ``ref`` implementations are mandatory.
+An op resolved to a backend with no registered implementation is an error,
+never a silent fall back to ``"ref"``; ``ref`` implementations are
+mandatory. Where an op's Pallas kernel does not cover a case (a direct
+factorization, a block past a kernel's VMEM cap), the op's resolution says
+``"ref"`` itself, so what :func:`resolutions` reports is what ran.
 """
 
 from __future__ import annotations
@@ -53,6 +59,10 @@ MIN_PALLAS_DIM = 128
 
 _TABLE: dict[str, dict[str, Callable]] = {}
 
+# (op, backend) -> name of the implementation traced for it, recorded by
+# _call; read through resolutions()
+_RAN: dict[tuple[str, str], str] = {}
+
 
 def register(op: str, backend: str, fn: Callable) -> None:
     """Register ``fn`` as the ``backend`` implementation of ``op``."""
@@ -64,7 +74,19 @@ def lookup(op: str, backend: str) -> Callable:
     if impls is None:
         raise KeyError(f"unregistered kernel op {op!r}; registered ops: "
                        f"{sorted(_TABLE)}")
-    return impls.get(backend, impls["ref"])
+    if backend not in impls:
+        raise KeyError(f"kernel op {op!r} has no {backend!r} implementation; "
+                       f"registered: {sorted(impls)}")
+    return impls[backend]
+
+
+def resolutions() -> dict[str, dict[str, str]]:
+    """Every op traced so far in this process: ``{op: {backend: impl}}``,
+    the backend its resolution picked and the implementation it called."""
+    out: dict[str, dict[str, str]] = {}
+    for (op, which), name in sorted(_RAN.items()):
+        out.setdefault(op, {})[which] = name
+    return out
 
 
 def _call(op: str, which: str, *args, **kwargs):
@@ -75,12 +97,26 @@ def _call(op: str, which: str, *args, **kwargs):
     tests that spy on lookup still observe every dispatch."""
     from repro.obs.tracing import kernel_scope
     fn = lookup(op, which)
+    _RAN[(op, which)] = fn.__name__
     with kernel_scope(op, which):
         return fn(*args, **kwargs)
 
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def _kernel_placeable() -> bool:
+    """Whether a compiled Mosaic kernel can lower in the region being
+    traced: one the compiler does not partition. That is a region with no
+    ambient mesh (a single-device ``jit``), a ``shard_map`` manual over every
+    mesh axis, or a ``jit`` over a one-device mesh."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return True
+    if mesh.manual_axes:
+        return set(mesh.manual_axes) == set(mesh.axis_names)
+    return mesh.size == 1
 
 
 def resolve(backend: str | None, *dims: int) -> str:
@@ -99,7 +135,8 @@ def resolve(backend: str | None, *dims: int) -> str:
         # unconditionally, sidestepping the MXU-worthiness gate
         raise ValueError('resolve("auto") needs at least one shape dim '
                          "(the quantities that predict the Pallas win)")
-    if _on_tpu() and all(d >= MIN_PALLAS_DIM for d in dims):
+    if (_on_tpu() and all(d >= MIN_PALLAS_DIM for d in dims)
+            and _kernel_placeable()):
         return "pallas"
     return "ref"
 
@@ -160,8 +197,6 @@ def _factor_sum_wire_pallas(x, max_dim: int, fmt: str, scale_mode: str):
     from repro.kernels import ops
     d = x.shape[-1]
     b = kfac.block_size(d, max_dim)
-    if b > ops.FACTOR_WIRE_MAX_DIM:
-        return _factor_sum_wire_ref(x, max_dim, fmt, scale_mode)
     xb = kfac.block_reshape(x, d, max_dim, axis=-1)   # (..., n, nb, b)
     xb = jnp.moveaxis(xb, -2, -3)                     # (..., nb, n, b)
     lead = xb.shape[:-2]
@@ -180,8 +215,13 @@ def factor_sum_wire(x: jax.Array, max_dim: int, *, fmt: str = "e4m3",
     """Fused statistics construction: blocked factor sum emitted directly
     in the sym-packed fp8 wire format (payload, per-block scale)."""
     from repro.core import kfac
+    from repro.kernels import ops
     b = kfac.block_size(x.shape[-1], max_dim)
     which = resolve(backend, b, x.shape[-2])
+    if b > ops.FACTOR_WIRE_MAX_DIM:
+        # the fused kernel keeps the whole block in VMEM: bigger blocks
+        # take the unfused XLA composition
+        which = "ref"
     return _call("factor_sum_wire", which, x, max_dim, fmt, scale_mode)
 
 
@@ -245,8 +285,7 @@ def block_precond_right(w: jax.Array, binv: jax.Array, *,
 # damped_inverse: (F + damping I)^-1 per block — the Stage-4 inversion.
 #
 # method "eigh" / "cholesky" are direct factorizations: not matmul-shaped,
-# so they are ref-only and the pallas backend routes them straight to ref
-# (the same op-by-op degradation as an unregistered op). method
+# so they have no kernel and their resolution is always ref. method
 # "newton_schulz" is matmul-only: ref = the jnp blocked iteration
 # (kfac.newton_schulz_inverse), pallas = the VMEM-resident kernel
 # (kernels/newton_schulz.py) — both share one failure contract: any block
@@ -311,9 +350,6 @@ def _damped_inverse_ref(f, damping, method: str, ns_iters: int,
 def _damped_inverse_pallas(f, damping, method: str, ns_iters: int,
                            ns_tol: float):
     from repro.kernels import ops
-    if method != "newton_schulz":
-        # direct methods degrade to ref in place
-        return _damped_inverse_ref(f, damping, method, ns_iters, ns_tol)
     b = f.shape[-1]
     f32 = f.astype(jnp.float32)
     m = 0.5 * (f32 + jnp.swapaxes(f32, -1, -2))
@@ -338,6 +374,8 @@ def damped_inverse(f: jax.Array, damping, *, method: str = "eigh",
     (and any monitoring hook's) view of which blocks took the eigh
     fallback; for the direct methods the residual is identically zero."""
     which = resolve(backend, f.shape[-1])
+    if method != "newton_schulz":
+        which = "ref"               # direct factorizations have no kernel
     inv, res = _call("damped_inverse", which, f, damping, method,
                       ns_iters, ns_tol)
     if return_info:

@@ -105,7 +105,8 @@ def _factor_wire_kernel(x_ref, payload_ref, scale_ref, acc_ref, *,
         if pow2:
             s = jnp.exp2(jnp.ceil(jnp.log2(jnp.maximum(s, 2.0 ** -126))))
         s = jnp.where(amax > 0, s, 1.0)
-        scale_ref[0, 0] = s
+        # a (1, 1) vector store: Mosaic cannot store a scalar to VMEM
+        scale_ref[...] = jnp.broadcast_to(s, (1, 1))
         q = jnp.clip(f / s, -fmt_max, fmt_max)   # e4m3fn overflows to NaN
         payload_ref[...] = q.astype(payload_ref.dtype)
 

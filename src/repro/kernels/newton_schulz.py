@@ -53,6 +53,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _ns_kernel(m_ref, x_ref, res_ref, *, iters: int, tol: float):
@@ -84,14 +85,26 @@ def _ns_kernel(m_ref, x_ref, res_ref, *, iters: int, tol: float):
     # residual of the RETURNED iterate (the in-loop value lags one step);
     # the dispatch layer reads res > tol as "failed to contract"
     r = eye - mm(m, x)
-    res_ref[...] = (jnp.sqrt(jnp.sum(r * r)) * rnorm).reshape(1, 1)
+    res_ref[...] = (jnp.sqrt(jnp.sum(r * r)) * rnorm).reshape(1, 1, 1)
     x_ref[...] = x[None]
+
+
+def _ns_vmem_limit(bp: int) -> int:
+    """Scoped-VMEM request for one resident block: the double-buffered
+    input and output blocks plus M, X and the step temporary in the body
+    (~7 b^2 f32) and headroom. At bp = 1024 that is ~36 MiB: past the
+    16 MiB default scope, well inside a v5e core's 128 MiB."""
+    return 9 * bp * bp * 4 + (4 << 20)
 
 
 def ns_inverse_blocks(m: jax.Array, *, iters: int, tol: float,
                       interpret: bool = False) -> tuple[jax.Array, jax.Array]:
     """m: (g, bp, bp) f32 symmetric damped blocks ->
-    (x (g, bp, bp) f32, res (g, 1) f32)."""
+    (x (g, bp, bp) f32, res (g, 1, 1) f32).
+
+    The residual block is (1, 1, 1) over a (g, 1, 1) array: its last two
+    dims equal the array's, which is what the TPU lowering accepts for a
+    block narrower than one (8, 128) tile."""
     g, bp, _ = m.shape
     grid = (g,)
     return pl.pallas_call(
@@ -100,12 +113,14 @@ def ns_inverse_blocks(m: jax.Array, *, iters: int, tol: float,
         in_specs=[pl.BlockSpec((1, bp, bp), lambda i: (i, 0, 0))],
         out_specs=[
             pl.BlockSpec((1, bp, bp), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((g, bp, bp), jnp.float32),
-            jax.ShapeDtypeStruct((g, 1), jnp.float32),
+            jax.ShapeDtypeStruct((g, 1, 1), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_ns_vmem_limit(bp)),
         interpret=interpret,
     )(m)
 

@@ -1,7 +1,9 @@
 """Jitted public wrappers around the Pallas kernels.
 
-On this container (CPU) the kernels execute in ``interpret=True`` mode; on a
-real TPU set ``interpret=False`` (the default flips on backend detection).
+Every wrapper takes ``interpret=None``, which :func:`interpret_mode`
+resolves: the kernels compile to Mosaic on a TPU and run under the Pallas
+interpreter on the CPU backend (tests). Passing ``interpret=False``
+explicitly compiles for the TPU whatever the default backend is.
 """
 
 from __future__ import annotations
@@ -20,8 +22,19 @@ from repro.kernels import quant_pack as _quant
 from repro.kernels import swa_attention as _swa
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def interpret_mode() -> bool:
+    """The one place interpret mode is decided: only the CPU backend runs
+    the kernels in the interpreter. A TPU compiles them, and any other
+    backend is an error rather than a quiet interpreter run."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(f"Pallas TPU kernels cannot run on the "
+                           f"{backend!r} backend")
+    return backend == "cpu"
+
+
+def _interpret(interpret: bool | None) -> bool:
+    return interpret_mode() if interpret is None else interpret
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
@@ -33,7 +46,7 @@ def kfac_factor(x: jax.Array, *, bm: int = 256, bn: int = 256, bk: int = 512,
     if bm != bn:
         raise ValueError(f"kfac_factor needs square tiling (diagonal tiles "
                          f"are mirrored in place); got bm={bm}, bn={bn}")
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = _interpret(interpret)
     n, d = x.shape
     bt = min(bm, d)
     bkk = min(bk, n)
@@ -68,7 +81,7 @@ def kfac_factor_wire(x: jax.Array, *, fmt: str = "e4m3",
     tril gather on 1-byte data (same row order as ``kfac.sym_pack``, so
     the emitted tile IS the PR-5 wire/storage tile)."""
     from repro.quant import quant as _q
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = _interpret(interpret)
     n, b = x.shape
     if b > FACTOR_WIRE_MAX_DIM:
         raise ValueError(f"kfac_factor_wire holds the whole block in VMEM; "
@@ -91,7 +104,7 @@ def kfac_block_precond(binv: jax.Array, w: jax.Array, *, bm: int = 256,
                        bn: int = 256, bk: int = 256,
                        interpret: bool | None = None) -> jax.Array:
     """Blocked preconditioner application U[k] = Binv[k] @ W[k]."""
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = _interpret(interpret)
     nb, b, _ = binv.shape
     m = w.shape[-1]
     bm_, bn_, bk_ = min(bm, b), min(bn, m), min(bk, b)
@@ -115,7 +128,7 @@ def swa_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   window: int = 0, bq: int = 256, bk: int = 256,
                   interpret: bool | None = None) -> jax.Array:
     """Causal sliding-window flash attention; (BH, S, hd) layout."""
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = _interpret(interpret)
     bh, s, hd = q.shape
     bq_, bk_ = min(bq, s), min(bk, s)
     bt = math.lcm(bq_, bk_)          # same grid-alignment rule as above
@@ -160,7 +173,7 @@ def ns_inverse(m: jax.Array, *, iters: int, tol: float,
     back to the caller's b so the fallback decision matches the unpadded
     reference iteration instead of being sqrt(bp/b) looser.
     """
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = _interpret(interpret)
     if m.shape[-1] > NS_KERNEL_MAX_DIM:
         raise ValueError(f"ns_inverse holds whole blocks in VMEM; "
                          f"b={m.shape[-1]} exceeds NS_KERNEL_MAX_DIM="
@@ -181,7 +194,7 @@ def ns_inverse(m: jax.Array, *, iters: int, tol: float,
     scale = math.sqrt(bp / b)
     x, res = _ns.ns_inverse_blocks(m, iters=iters, tol=tol / scale,
                                    interpret=interpret)
-    return x[:, :b, :b], res[:, 0] * scale
+    return x[:, :b, :b], res[:, 0, 0] * scale
 
 
 def _ns_tile(bp: int) -> int:
@@ -212,7 +225,7 @@ def ns_inverse_tiled(m: jax.Array, *, iters: int, tol: float,
     unpadded ||I_b||_F), except blocks pad to the tile size so the grid
     needs no edge masking.
     """
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = _interpret(interpret)
     g, b, _ = m.shape
     bt = _ns_tile(-(-b // 128) * 128)
     bp = -(-b // bt) * bt
@@ -265,7 +278,7 @@ def fp8_quant_rows(x: jax.Array, *, fmt: str = "e4m3",
     scale f32 (...,)). Rows are whole quantization tiles (one scale each);
     for sym-packed factors a row is one block's packed lower triangle."""
     from repro.quant import quant as _q
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = _interpret(interpret)
     lead, t = x.shape[:-1], x.shape[-1]
     flat = x.reshape((-1, t))
     g = flat.shape[0]
@@ -285,7 +298,7 @@ def fp8_quant_rows(x: jax.Array, *, fmt: str = "e4m3",
 def fp8_dequant_rows(payload: jax.Array, scale: jax.Array, *, bg: int = 8,
                      interpret: bool | None = None) -> jax.Array:
     """Inverse of :func:`fp8_quant_rows`: fp8 payload + per-row scale -> f32."""
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = _interpret(interpret)
     lead, t = payload.shape[:-1], payload.shape[-1]
     flat = payload.reshape((-1, t))
     g = flat.shape[0]
@@ -314,7 +327,7 @@ def swa_decode(q: jax.Array, k: jax.Array, v: jax.Array, pos: jax.Array,
     ``window > 0`` means C == window and the cache is a RING buffer (token
     at position p lives in slot p % window); ``window == 0`` attends the
     dense cache full-causally. Returns (N, G, hd) f32."""
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = _interpret(interpret)
     n, g, hd = q.shape
     c = k.shape[1]
     if window and c != window:
@@ -336,8 +349,9 @@ def swa_decode(q: jax.Array, k: jax.Array, v: jax.Array, pos: jax.Array,
     # the dequant (cast + scale multiply) happens on read in VMEM, so the
     # f32 cache never exists in HBM
     return _swa.swa_flash_decode(
-        q, k, v, k_scale.astype(jnp.float32), v_scale.astype(jnp.float32),
-        pos.astype(jnp.int32).reshape(n, 1), window=window, cache_len=c,
+        q, k, v, k_scale.astype(jnp.float32).reshape(n, 1, cp),
+        v_scale.astype(jnp.float32).reshape(n, 1, cp),
+        pos.astype(jnp.int32).reshape(n), window=window, cache_len=c,
         bk=bk_, interpret=interpret)
 
 
@@ -349,7 +363,7 @@ def swa_attention_fwd_res(q: jax.Array, k: jax.Array, v: jax.Array, *,
     """Residual-saving training forward, GQA layout: q (BKV, G, S, hd),
     k/v (BKV, S, hd) — KV unexpanded, one kernel batch entry per KV head.
     Returns (out (BKV, G, S, hd), lse (BKV, G, S) f32)."""
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = _interpret(interpret)
     bkv, g, s, hd = q.shape
     bq_, bk_ = min(bq, s), min(bk, s)
     sp = _pad_seq(s, bq_, bk_)
@@ -372,7 +386,7 @@ def swa_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
     recompute. Layouts as in :func:`swa_attention_fwd_res`; returns
     (dq (BKV, G, S, hd), dk (BKV, S, hd), dv (BKV, S, hd)), all f32 with
     dk/dv accumulated per KV head across the query-head group."""
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = _interpret(interpret)
     bkv, g, s, hd = q.shape
     bq_, bk_ = min(bq, s), min(bk, s)
     # D_i = rowsum(do * o) once on the XLA side (FlashAttention-2 style):

@@ -160,7 +160,7 @@ def _swa_decode_kernel(q_ref, k_ref, ks_ref, v_ref, vs_ref, pos_ref, o_ref,
         d_ref[...] = jnp.zeros_like(d_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    pos = pos_ref[0, 0]
+    pos = pos_ref[pl.program_id(0)]
     # dense mode: blocks whose first slot is past the query position hold
     # nothing visible — skip the compute (the ring mode visits every block:
     # capacity == window means every resident slot is in the band)
@@ -171,8 +171,8 @@ def _swa_decode_kernel(q_ref, k_ref, ks_ref, v_ref, vs_ref, pos_ref, o_ref,
         q = q_ref[0].astype(jnp.float32) * scale          # (G, hd)
         k = k_ref[0].astype(jnp.float32)                  # (bk, hd)
         v = v_ref[0].astype(jnp.float32)
-        k = k * ks_ref[0][:, None]
-        v = v * vs_ref[0][:, None]
+        k = k * ks_ref[0, 0][:, None]
+        v = v * vs_ref[0, 0][:, None]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         sl = j * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
@@ -210,7 +210,10 @@ def swa_flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
                      interpret: bool = False) -> jax.Array:
     """Single-query GQA flash decode. q (N, G, hd); k/v (N, Cp, hd) cache
     payload (fp8 or dense dtype, Cp = lane-padded capacity); k_scale/v_scale
-    (N, Cp) f32 per-row dequant scales (ones for dense); pos (N, 1) i32.
+    (N, 1, Cp) f32 per-row dequant scales (ones for dense) — the unit axis
+    makes each (1, bk) scale block span the array's full second-minor dim,
+    which the TPU lowering requires of a block one row tall; pos (N,) i32,
+    held whole in SMEM and indexed by the grid's sequence coordinate.
     ``window`` > 0 = ring layout of capacity ``window``; 0 = dense cache of
     ``cache_len`` valid slots. Returns (N, G, hd) f32."""
     n, g, hd = q.shape
@@ -221,7 +224,7 @@ def swa_flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
     scale = hd ** -0.5
 
     kv_spec = pl.BlockSpec((1, bk_, hd), lambda b, j: (b, j, 0))
-    sc_spec = pl.BlockSpec((1, bk_), lambda b, j: (b, j))
+    sc_spec = pl.BlockSpec((1, 1, bk_), lambda b, j: (b, 0, j))
     return pl.pallas_call(
         functools.partial(_swa_decode_kernel, bk=bk_, window=window,
                           cache_len=cache_len, n_k=n_k, scale=scale),
@@ -229,8 +232,7 @@ def swa_flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
         in_specs=[
             pl.BlockSpec((1, g, hd), lambda b, j: (b, 0, 0)),
             kv_spec, sc_spec, kv_spec, sc_spec,
-            pl.BlockSpec((1, 1), lambda b, j: (b, 0),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((1, g, hd), lambda b, j: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, g, hd), jnp.float32),

@@ -32,7 +32,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config, list_archs, INPUT_SHAPES
 from repro.configs.base import ArchConfig, InputShape
 from repro.core.ngd import NGDConfig, SPNGD
-from repro.launch import compat
 from repro.launch import sharding as shd
 from repro.launch.mesh import make_production_mesh
 from repro.launch.roofline import (analyze_hlo, roofline_terms,
@@ -277,7 +276,7 @@ def run_case(arch: str, shape_name: str, multi_pod: bool,
            "refresh_chunks": refresh_chunks,
            "mesh": "2x16x16" if multi_pod else "16x16", "chips": n_chips}
     try:
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             step, args, n_params, label = build_case(
                 arch, shape_name, mesh, schedule=schedule, tp_align=tp_align,
                 rwkv_chunk=rwkv_chunk, fast=fast, backend=backend,
@@ -310,7 +309,7 @@ def run_case(arch: str, shape_name: str, multi_pod: bool,
             compiled = lowered.compile()
             t2 = time.time()
             mem = compiled.memory_analysis()
-            cost = compat.cost_analysis(compiled)
+            cost = compiled.cost_analysis()
             hlo = compiled.as_text()
         ana = analyze_hlo(hlo)
         # the compiled module is the per-device SPMD program: scale to global

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.ngd import SPNGD
-from repro.launch import compat
 
 
 def _check_accum_capture(opt: SPNGD, accum: int) -> None:
@@ -203,11 +202,11 @@ def make_shardmap_train_step(model, opt: SPNGD, mesh, accum: int = 1,
         counts = model.site_counts(batch)
         batch_specs = jax.tree.map(
             lambda x: P(dp, *(None,) * (x.ndim - 1)), batch)
-        sm = compat.shard_map(
+        sm = jax.shard_map(
             inner, mesh=mesh,
             in_specs=(P(), batch_specs),
             out_specs=(P(), P(), reducer.out_specs()),
-            axis_names=set(dp))
+            axis_names=set(dp), check_vma=False)
         loss, grads, raw = sm(params, batch)
         return opt.apply_update(params, opt_state, grads, raw, counts,
                                 flags, lam, lr, mom, loss, {})
@@ -257,8 +256,9 @@ def make_shardmap_fast_step(model, opt: SPNGD, mesh, accum: int = 1,
     def fast_step(params, opt_state, batch, lam, lr, mom):
         batch_specs = jax.tree.map(
             lambda x: P(dp, *(None,) * (x.ndim - 1)), batch)
-        sm = compat.shard_map(inner, mesh=mesh, in_specs=(P(), batch_specs),
-                              out_specs=(P(), P()), axis_names=set(dp))
+        sm = jax.shard_map(inner, mesh=mesh, in_specs=(P(), batch_specs),
+                           out_specs=(P(), P()), axis_names=set(dp),
+                           check_vma=False)
         loss, grads = sm(params, batch)
         # fast_curv drains one refresh-pipeline chunk (refresh_chunks > 1)
         # or performs the plain double-buffer activation. The drain runs
@@ -291,14 +291,23 @@ def make_prefill_step(model) -> Callable:
 # overhead-accounting probe (repro.obs; make_report.py's decomposition input)
 # ---------------------------------------------------------------------------
 
-def _probe_time(fn, *args, iters: int = 3) -> float:
-    """Median wall-µs of ``fn(*args)`` after one compile+warm call."""
+def _probe_time(fn, *args, iters: int = 3, donated: tuple = ()) -> float:
+    """Median wall-µs of ``fn(*args)`` after one compile+warm call. The
+    arguments at positions ``donated`` are consumed by each call (buffer
+    donation), so every call gets its own copy, made outside the timed
+    window."""
     import time as _time
-    jax.block_until_ready(fn(*args))
+
+    def fresh():
+        return [jax.tree.map(jnp.copy, a) if i in donated else a
+                for i, a in enumerate(args)]
+
+    jax.block_until_ready(fn(*fresh()))
     ts = []
     for _ in range(iters):
+        call_args = jax.block_until_ready(fresh())
         t0 = _time.perf_counter()
-        jax.block_until_ready(fn(*args))
+        jax.block_until_ready(fn(*call_args))
         ts.append((_time.perf_counter() - t0) * 1e6)
     return sorted(ts)[len(ts) // 2]
 
@@ -329,9 +338,11 @@ def _overhead_probe(opt, step_j, fast_j, params, state, batch, args,
 
     fwd_bwd_us = _probe_time(fwd_bwd_j, params, batch)
     capture_us = _probe_time(capture_j, params, batch)
-    fast_us = _probe_time(fast_j, params, state, batch, lam, lr0, mom0)
+    # the step programs donate params and optimizer state
+    fast_us = _probe_time(fast_j, params, state, batch, lam, lr0, mom0,
+                          donated=(0, 1))
     refresh_us = _probe_time(step_j, params, state, batch, all_on,
-                             lam, lr0, mom0)
+                             lam, lr0, mom0, donated=(0, 1))
 
     # Stage-4 inversion in isolation: one damped_inverse per full-kind
     # factor on an SPD stand-in shaped like the real statistic
@@ -361,14 +372,9 @@ def _overhead_probe(opt, step_j, fast_j, params, state, batch, args,
 # CLI launcher: train any --arch (reduced) on the synthetic LM task
 # ---------------------------------------------------------------------------
 
-def main():
+def build_parser():
+    """The trainer's command line; :func:`run` takes its parsed settings."""
     import argparse
-
-    from repro.configs import get_config
-    from repro.core.stale import IntervalController
-    from repro.data.synthetic import token_batches
-    from repro.models.transformer import DecoderLM
-    from repro.optim.schedules import polynomial_decay
 
     ap = argparse.ArgumentParser(
         description="SP-NGD trainer (reduced configs on CPU; the full "
@@ -466,25 +472,39 @@ def main():
                     help="skip the stage-isolated timing probe that "
                          "metrics-enabled runs emit for make_report.py's "
                          "overhead-accounting table")
-    args = ap.parse_args()
+    return ap
 
+
+def run(cfg, args, *, label: str, on_step: Optional[Callable] = None):
+    """Train ``cfg`` on the synthetic LM task with the settings ``args``
+    (:func:`build_parser`'s namespace; ``args.arch``/``args.full_config``
+    are not read — ``cfg`` is the architecture and ``label`` names it on
+    the console). Both step programs donate params and optimizer state.
+
+    ``on_step(record)``, when given, is called after every step with
+    ``{"step", "program", "loss", "grad_norm", "update_norm", "dt"}``
+    (``program`` is ``"train_step"`` or ``"fast_step"``; ``dt`` waits for
+    the step's loss). Returns ``{"params", "state", "opt", "programs",
+    "batch"}``: the final parameters and optimizer state, the optimizer,
+    the two jitted step programs, and the last batch."""
     import dataclasses
 
+    from repro import comm as comm_lib
     from repro.core.ngd import NGDConfig, SPNGD
+    from repro.core.stale import IntervalController
+    from repro.data.synthetic import token_batches
+    from repro.models.transformer import DecoderLM
     from repro.obs import (STAGE_CHUNK, MetricsLogger, ProfileCapture,
                            inverse_tally)
+    from repro.optim.schedules import polynomial_decay
+    from repro.quant import FACTOR_DTYPES
 
     log = MetricsLogger(args.metrics_jsonl)
-    cfg = get_config(args.arch)
-    if not args.full_config:
-        cfg = cfg.reduced()
     cfg = dataclasses.replace(cfg, backend=args.backend)
     model = DecoderLM(cfg)
     params = model.init(jax.random.PRNGKey(0))
     n = sum(x.size for x in jax.tree.leaves(params))
-    log.console(f"arch={args.arch} "
-                f"({'full' if args.full_config else 'reduced'}), "
-                f"{n / 1e6:.1f}M params")
+    log.console(f"{label}, {n / 1e6:.1f}M params")
 
     inverse_sharding = args.inverse_sharding
     refresh_chunks = max(1, args.refresh_chunks)
@@ -526,10 +546,14 @@ def main():
                       "refresh_chunks": refresh_chunks})
     data = token_batches(cfg.vocab, args.batch, args.seq, seed=0)
     lr_fn = polynomial_decay(args.lr, 0, args.steps, 4.0)
-    step_j = jax.jit(make_train_step(model, opt, accum=args.accum))
-    fast_j = jax.jit(make_fast_step(model, opt, accum=args.accum))
+    # params and optimizer state are consumed by each step: without
+    # donation a full-width step holds both the old and the new copies
+    step_j = jax.jit(make_train_step(model, opt, accum=args.accum),
+                     donate_argnums=(0, 1))
+    fast_j = jax.jit(make_fast_step(model, opt, accum=args.accum),
+                     donate_argnums=(0, 1))
 
-    log.emit("run_config", arch=args.arch, full_config=args.full_config,
+    log.emit("run_config", arch=cfg.name, full_config=args.full_config,
              n_params=int(n), steps=args.steps, batch=args.batch,
              seq=args.seq, accum=args.accum, lr=args.lr,
              damping=args.damping, backend=args.backend,
@@ -575,9 +599,17 @@ def main():
             params, state, m = fast_j(params, state, batch,
                                       args.damping, lr, mom)
             ctrl.update(t, flags, {})
-        if log.enabled:
+        if on_step is not None or log.enabled:
             jax.block_until_ready(m["loss"])
             dt = _time.perf_counter() - t0
+        if on_step is not None:
+            on_step({"step": t,
+                     "program": ("train_step" if any(flags.values())
+                                 else "fast_step"),
+                     "loss": float(m["loss"]),
+                     "grad_norm": float(m["grad_norm"]),
+                     "update_norm": float(m["update_norm"]), "dt": dt})
+        if log.enabled:
             # chunked pipeline: refresh-trigger steps are CAPTUREs (no
             # inversion runs inline), so the stream's "refresh" kind —
             # which make_report amortizes the inline Stage-3/4 costs
@@ -632,6 +664,21 @@ def main():
                     f"{s['comm']['total_gather_bytes']} B")
     log.emit("summary", **ctrl.summary_flat())
     log.close()
+    return {"params": params, "state": state, "opt": opt, "batch": batch,
+            "programs": {"train_step": step_j, "fast_step": fast_j}}
+
+
+def main():
+    from repro.configs import get_config
+    from repro.launch.cache import use_compile_cache
+
+    args = build_parser().parse_args()
+    use_compile_cache()
+    cfg = get_config(args.arch)
+    if not args.full_config:
+        cfg = cfg.reduced()
+    run(cfg, args, label=(f"arch={args.arch} "
+                          f"({'full' if args.full_config else 'reduced'})"))
 
 
 if __name__ == "__main__":

@@ -157,10 +157,10 @@ def test_kfac_factor_rejects_rectangular_tiles():
 
 
 def test_direct_inverse_methods_degrade_pallas_to_ref():
-    # eigh/cholesky are not matmul-shaped, so the pallas damped_inverse impl
-    # must route them to the ref callable bit-for-bit (the same op-by-op
-    # degradation an unregistered op gets); only method="newton_schulz"
-    # engages the kernel
+    # eigh/cholesky are not matmul-shaped, so damped_inverse resolves them
+    # to the ref callable even under backend="pallas" (bit-for-bit, and
+    # reported as ref by dispatch.resolutions()); only
+    # method="newton_schulz" engages the kernel
     rng = np.random.RandomState(1)
     m = rng.randn(2, 8, 8)
     f = jnp.asarray(m @ m.transpose(0, 2, 1) + 8 * np.eye(8), jnp.float32)
@@ -170,17 +170,21 @@ def test_direct_inverse_methods_degrade_pallas_to_ref():
         b = dispatch.damped_inverse(f, jnp.asarray(1e-3), method=method,
                                     backend="pallas")
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert dispatch.resolutions()["damped_inverse"]["ref"] == \
+        "_damped_inverse_ref"
 
 
 def test_unregistered_backend_falls_back_to_ref():
-    # ops are ported one at a time: an op with no impl for the resolved
-    # backend must fall back to ref instead of failing
+    # an op with no impl for the resolved backend fails rather than run
+    # ref silently (that would hide a missing kernel on the chip); the ref
+    # impl stays reachable by resolving to "ref" explicitly
     def only_ref(x):
         return x + 1.0
     dispatch.register("only_ref_op", "ref", only_ref)
     try:
-        fn = dispatch.lookup("only_ref_op", "pallas")
-        assert fn is only_ref
+        with pytest.raises(KeyError, match="no 'pallas' implementation"):
+            dispatch.lookup("only_ref_op", "pallas")
+        assert dispatch.lookup("only_ref_op", "ref") is only_ref
     finally:
         dispatch._TABLE.pop("only_ref_op", None)
 
@@ -260,15 +264,15 @@ def test_train_step_backends_match_20_steps():
 
 @pytest.mark.slow
 def test_shardmap_train_step_backends_match():
-    from repro.launch import compat
+    from repro.launch.mesh import make_mesh
     from repro.launch.train import make_shardmap_train_step
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 virtual devices")
     losses = {}
     for backend in ("ref", "pallas"):
         model, opt, params, state, batch, flags = _tiny_setup(backend)
-        mesh = compat.make_mesh((4, 2), ("data", "model"))
-        with compat.set_mesh(mesh):
+        mesh = make_mesh((4, 2), ("data", "model"))
+        with jax.set_mesh(mesh):
             step = jax.jit(make_shardmap_train_step(model, opt, mesh))
             out = []
             for _ in range(20):
@@ -281,3 +285,40 @@ def test_shardmap_train_step_backends_match():
     np.testing.assert_allclose(losses["ref"][:8], losses["pallas"][:8],
                                rtol=1e-3, atol=1e-3)
     assert max(losses["ref"][8:]) < 1.0 and max(losses["pallas"][8:]) < 1.0
+
+
+def test_auto_resolves_ref_where_the_compiler_partitions(monkeypatch):
+    # Mosaic kernels are not partitioned automatically: on a TPU, auto
+    # picks a kernel only where the traced region is one device or a
+    # shard_map manual over every mesh axis
+    from jax.sharding import PartitionSpec as P
+
+    from repro.launch.mesh import make_mesh
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 virtual devices")
+    monkeypatch.setattr(dispatch, "_on_tpu", lambda: True)
+    mesh = make_mesh((4, 1), ("data", "model"))
+    seen = {}
+
+    def probe(tag):
+        def f(x):
+            seen[tag] = dispatch.resolve("auto", 256)
+            return x
+        return f
+
+    x = jnp.zeros((8, 4))
+    jax.jit(probe("no_mesh")).lower(x)
+    with jax.set_mesh(mesh):
+        jax.jit(probe("jit_on_mesh")).lower(x)
+        for tag, axes in (("manual_data", {"data"}),
+                          ("manual_all", {"data", "model"})):
+            jax.jit(jax.shard_map(
+                probe(tag), mesh=mesh, in_specs=P("data"),
+                out_specs=P("data"), axis_names=axes,
+                check_vma=False)).lower(x)
+    with jax.set_mesh(make_mesh((1, 1), ("data", "model"))):
+        jax.jit(probe("jit_one_device_mesh")).lower(x)
+    assert seen == {"no_mesh": "pallas", "jit_on_mesh": "ref",
+                    "manual_data": "ref", "manual_all": "pallas",
+                    "jit_one_device_mesh": "pallas"}
+    assert dispatch.resolve("pallas", 256) == "pallas"   # explicit: as asked

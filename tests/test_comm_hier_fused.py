@@ -31,7 +31,7 @@ from repro.comm import (CommConfig, FactorReducer, hier_split,
                         wire_stat_level_bytes)
 from repro.core.stale import IntervalController, sym_packed_bytes
 from repro.kernels import dispatch
-from repro.launch import compat
+from repro.launch.mesh import make_mesh
 from repro.quant import encoded_nbytes
 
 needs_devices = pytest.mark.skipif(len(jax.devices()) < 8,
@@ -131,10 +131,20 @@ def test_factor_sum_wire_ref_vs_pallas(scale_mode):
                                            backend="pallas")
     t = 16 * 17 // 2
     assert pay_r.shape == (3, 2, t) and sc_r.shape == (3, 2)
-    # identical scale math (explicit reciprocal-multiply in both paths)
-    np.testing.assert_array_equal(np.asarray(sc_r), np.asarray(sc_p))
-    np.testing.assert_array_equal(np.asarray(pay_r).view(np.uint8),
-                                  np.asarray(pay_p).view(np.uint8))
+    # The two paths sum the same products in a different order (XLA's
+    # einsum vs the kernel's dot over one VMEM tile), so each block's f32
+    # amax may differ in its last bit; the scale math on top is the same
+    # explicit reciprocal-multiply in both (pinned bit-exact on equal
+    # inputs by the fp8_pack parity tests). Scales therefore agree to a
+    # few f32 ulps (a 64-term sum's reordering error), and a payload byte
+    # may move to the adjacent fp8 code where a scaled value sits on a
+    # rounding boundary.
+    np.testing.assert_allclose(np.asarray(sc_r), np.asarray(sc_p),
+                               rtol=1e-6, atol=0)
+    codes_r = np.asarray(pay_r).view(np.uint8).astype(np.int32)
+    codes_p = np.asarray(pay_p).view(np.uint8).astype(np.int32)
+    assert np.abs(codes_r - codes_p).max() <= 1
+    assert (codes_r != codes_p).mean() <= 0.01
     # decode matches the dense factor sum within the e4m3 bound
     from repro import quant
     dense = dispatch.factor_sum(x, 16, backend="ref")
@@ -160,9 +170,9 @@ def _reduce_with(mesh, manual_axes, comm, raw_all, template, sym_fn):
         return red.reduce(jax.tree.map(lambda x: x[0], raw))
 
     in_specs = jax.tree.map(lambda _: P(red.dp), raw_all)
-    fn = compat.shard_map(body, mesh=mesh, in_specs=(in_specs,),
-                          out_specs=red.out_specs(),
-                          axis_names=set(red.dp))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(in_specs,),
+                       out_specs=red.out_specs(),
+                       axis_names=set(red.dp), check_vma=False)
     return jax.tree.map(np.asarray, jax.jit(fn)(raw_all)), red
 
 
@@ -171,7 +181,7 @@ def _reduce_with(mesh, manual_axes, comm, raw_all, template, sym_fn):
 def test_hier_reduce_parity_two_level(devices_per_host):
     """hier vs dense on an 8-device group modelled as 2 hosts x 4 devices
     (plus the degenerate pure-ring and pure-psum_scatter splits)."""
-    mesh = compat.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     shapes = {"a": (8, 2, 16, 16),        # symmetric: fp8 inter-host ring
               "d": (8, 6)}                # non-symmetric: f32 both levels
     template = _template(shapes)
@@ -207,7 +217,7 @@ def test_hier_reduce_parity_two_level(devices_per_host):
 
 @needs_devices
 def test_hier_level_ledger_on_mesh():
-    mesh = compat.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     shapes = {"a": (8, 2, 16, 16), "uw": (3, 4)}   # uw: replicated fallback
     red = FactorReducer(mesh, manual_axes="all",
                         comm=make_comm_config("hier", devices_per_host=4),
@@ -299,8 +309,8 @@ def test_fused_spy_syrk_emits_wire_no_ring_hop_pack(monkeypatch):
 
     monkeypatch.setattr(dispatch, "lookup", spy)
     model, opt, params, state, batch, flags = _setup(factor_wire="e4m3")
-    mesh = compat.make_mesh((2, 4), ("data", "model"))
-    with compat.set_mesh(mesh):
+    mesh = make_mesh((2, 4), ("data", "model"))
+    with jax.set_mesh(mesh):
         step = make_shardmap_train_step(model, opt, mesh,
                                         comm=make_comm_config("fused"))
         jax.jit(step).lower(params, state, batch, flags,
@@ -334,8 +344,8 @@ def test_e2e_fused_matches_dense_20_steps():
             ("fused_jit", "e4m3", None, False)):
         model, opt, params, state, batch, flags = _setup(factor_wire=wire)
         if sharded:
-            mesh = compat.make_mesh((2, 4), ("data", "model"))
-            with compat.set_mesh(mesh):
+            mesh = make_mesh((2, 4), ("data", "model"))
+            with jax.set_mesh(mesh):
                 step = jax.jit(make_shardmap_train_step(
                     model, opt, mesh, comm=make_comm_config(strat)))
                 out = []
@@ -371,12 +381,12 @@ def test_e2e_hier_matches_dense_20_steps():
     2-host x 4-device topology. Mesh (8, 1) with n_layers=8 so the layer
     axis scatters 8-ways and both hier levels run."""
     from repro.launch.train import make_shardmap_train_step
-    mesh = compat.make_mesh((8, 1), ("data", "model"))
+    mesh = make_mesh((8, 1), ("data", "model"))
     losses = {}
     for strat in ("dense", "hier"):
         model, opt, params, state, batch, flags = _setup(n_layers=8)
         comm = make_comm_config(strat, devices_per_host=4)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             step = jax.jit(make_shardmap_train_step(model, opt, mesh,
                                                     comm=comm))
             out = []

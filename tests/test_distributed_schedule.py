@@ -19,7 +19,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config
 from repro.core.ngd import NGDConfig, SPNGD
-from repro.launch import compat
+from repro.launch.mesh import make_mesh
 from repro.launch.train import make_train_step, make_shardmap_train_step
 from repro.models.transformer import DecoderLM
 
@@ -47,7 +47,7 @@ def _setup(arch="llama3_2_1b"):
 
 
 def _mesh():
-    return compat.make_mesh((4, 2), ("data", "model"))
+    return make_mesh((4, 2), ("data", "model"))
 
 
 @pytest.mark.parametrize("accum", [
@@ -59,7 +59,7 @@ def test_shardmap_matches_single_device(accum):
     p_ref, s_ref, m_ref = jax.jit(ref_step)(params, state, batch, flags,
                                             1e-3, 1e-2, 0.9)
     mesh = _mesh()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         sm_step = make_shardmap_train_step(model, opt, mesh, accum=accum)
         p_sm, s_sm, m_sm = jax.jit(sm_step)(params, state, batch, flags,
                                             1e-3, 1e-2, 0.9)
@@ -82,7 +82,7 @@ def test_shardmap_matches_single_device(accum):
 def test_shardmap_hlo_has_reduce_scatter():
     model, opt, params, state, batch, flags = _setup()
     mesh = _mesh()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         sm_step = make_shardmap_train_step(model, opt, mesh, accum=1)
         hlo = jax.jit(sm_step).lower(params, state, batch, flags,
                                      1e-3, 1e-2, 0.9).compile().as_text()
@@ -93,7 +93,7 @@ def test_shardmap_hlo_has_reduce_scatter():
 def test_shardmap_loss_decreases():
     model, opt, params, state, batch, flags = _setup()
     mesh = _mesh()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         sm_step = jax.jit(make_shardmap_train_step(model, opt, mesh, accum=2))
         losses = []
         for _ in range(5):

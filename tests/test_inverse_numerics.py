@@ -347,7 +347,7 @@ def test_train_20_steps_ns_matches_eigh_jit():
 
 @pytest.mark.slow
 def test_train_20_steps_ns_matches_eigh_shardmap():
-    from repro.launch import compat
+    from repro.launch.mesh import make_mesh
     from repro.launch.train import make_shardmap_train_step
     from test_backend_dispatch import _tiny_setup
     if len(jax.devices()) < 8:
@@ -357,8 +357,8 @@ def test_train_20_steps_ns_matches_eigh_shardmap():
                               ("ns", "pallas",
                                {"inverse_method": "newton_schulz"})):
         model, opt, params, state, batch, flags = _tiny_setup(backend, **kw)
-        mesh = compat.make_mesh((4, 2), ("data", "model"))
-        with compat.set_mesh(mesh):
+        mesh = make_mesh((4, 2), ("data", "model"))
+        with jax.set_mesh(mesh):
             step = jax.jit(make_shardmap_train_step(model, opt, mesh))
             out = []
             for _ in range(20):

@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.launch import compat
+from repro.launch.mesh import make_mesh
 from repro.launch.roofline import analyze_hlo
 
 
@@ -20,7 +20,7 @@ def test_dot_flops_match_cost_analysis():
     ana = analyze_hlo(c.as_text())
     expect = 2 * 128 * 256 * 64
     assert abs(ana.flops - expect) / expect < 0.05, (ana.flops, expect)
-    ca = compat.cost_analysis(c)
+    ca = c.cost_analysis()
     if ca and ca.get("flops"):
         assert abs(ana.flops - ca["flops"]) / ca["flops"] < 0.1
 
@@ -89,7 +89,7 @@ def test_collective_bytes_counted():
     devs = jax.devices()
     if len(devs) < 2:
         pytest.skip("needs >= 2 devices")
-    mesh = compat.make_mesh((2,), ("x",))
+    mesh = make_mesh((2,), ("x",))
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     def f(a):
@@ -99,7 +99,7 @@ def test_collective_bytes_counted():
 
     a = jax.ShapeDtypeStruct((128, 128), jnp.float32,
                              sharding=NamedSharding(mesh, P(None, "x")))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         c = jax.jit(f).lower(a).compile()
     ana = analyze_hlo(c.as_text())
     assert ana.collective_bytes > 0

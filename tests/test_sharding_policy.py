@@ -6,7 +6,6 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config
-from repro.launch import compat
 from repro.launch import sharding as shd
 from repro.launch.mesh import make_test_mesh
 from repro.models.transformer import DecoderLM
@@ -94,10 +93,10 @@ def test_cache_pspecs_gqa_and_long_context(mesh):
 def test_factor_sharding_hook_uneven_ok(mesh):
     hook = shd.factor_sharding_hook(mesh)
     x = jnp.zeros((5, 2, 8, 8))             # L=5 not divisible by 4
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = jax.jit(lambda x: hook("blk/test", "a", x))(x)
     assert out.shape == x.shape
     y = jnp.zeros((3,))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = jax.jit(lambda y: hook("embed", "a", y))(y)  # non-blk: untouched
     assert out.shape == y.shape

@@ -32,7 +32,7 @@ from repro.comm import (FactorReducer, Stage4Inverter, gather_stat_bytes,
 from repro.core.ngd import NGDConfig, SPNGD
 from repro.core.stale import IntervalController, sym_packed_bytes
 from repro.kernels import dispatch
-from repro.launch import compat
+from repro.launch.mesh import make_mesh
 
 needs_devices = pytest.mark.skipif(len(jax.devices()) < 8,
                                    reason="needs 8 virtual devices")
@@ -74,7 +74,7 @@ def test_template_gather_bytes_full_factors_only():
 
 @needs_devices
 def test_reducer_gather_bytes_respect_scatter_decisions():
-    mesh = compat.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     template = {"fam": {
         "a": jax.ShapeDtypeStruct((8, 2, 16, 16), jnp.float32),   # scatters
         "g": jax.ShapeDtypeStruct((6, 2, 16, 16), jnp.float32),   # fallback
@@ -139,7 +139,7 @@ def test_each_device_inverts_only_its_shard():
     contiguous chunk i — the psum_scatter(tiled=True) chunk assignment the
     Stage-3 reducer scattered with — and the gathered preconditioner must
     match the replicated inverse."""
-    mesh = compat.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     lead, nb, b = 16, 2, 8
     template = {"fam": {"a": jax.ShapeDtypeStruct((lead, nb, b, b),
                                                   jnp.float32)}}
@@ -154,7 +154,7 @@ def test_each_device_inverts_only_its_shard():
     np.testing.assert_array_equal(inv4.owners(lead),
                                   np.repeat(np.arange(8, dtype=np.int32), 2))
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         inv, info = jax.jit(
             lambda f, d: inv4.invert(f, d, fam="fam", key="a",
                                      return_info=True))(f, damp)
@@ -169,7 +169,7 @@ def test_each_device_inverts_only_its_shard():
 
 @needs_devices
 def test_indivisible_leading_dim_falls_back_to_replicated():
-    mesh = compat.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     lead, nb, b = 6, 1, 8                    # 6 % 4 != 0: cannot scatter
     red = FactorReducer(mesh, template={"fam": {
         "a": jax.ShapeDtypeStruct((lead, nb, b, b), jnp.float32)}},
@@ -306,9 +306,9 @@ def _losses_shardmap(strategy, steps=20, period=1, offset=0, lr=2e-3,
     from repro.launch.train import (make_shardmap_fast_step,
                                     make_shardmap_train_step)
     # (2, 4): the layer axis (L=2) scatters, so Stage-4 actually shards
-    mesh = compat.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     model, opt, params, state, batch, flags = _llama_setup(ngd_kw)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         comm = make_comm_config(strategy)
         step = jax.jit(make_shardmap_train_step(model, opt, mesh, comm=comm))
         # period > 1: capture on steps t % period == offset, fast steps in
