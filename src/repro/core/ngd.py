@@ -30,6 +30,9 @@ import jax.numpy as jnp
 
 from repro.core import kfac
 from repro.core.fisher import SiteInfo, emp_fisher_grads, mc_fisher_grads, get_path, set_path
+from repro.obs.tracing import (STAGE_CAPTURE, STAGE_DRAIN, STAGE_FWD_BWD,
+                               STAGE_HISTORY, STAGE_INVERSE, STAGE_PRECOND,
+                               STAGE_STATS, STAGE_UPDATE)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -322,6 +325,7 @@ class SPNGD:
 
     # ---- curvature refresh (Algorithm 1's on-refresh work) ----
 
+    @jax.named_scope(STAGE_HISTORY)
     def _shift_history(self, fam: str, raw: dict, curv: dict,
                        flags: dict, n_a, n_g):
         """Per-family pre-inversion refresh work: decode + normalize the raw
@@ -389,32 +393,34 @@ class SPNGD:
         normalized, new_prev, new_prev2, sims = self._shift_history(
             fam, raw, curv, flags, n_a, n_g)
 
-        any_flag = functools.reduce(
-            jnp.logical_or, [flags[f"{fam}.{k}"] for k in raw], jnp.asarray(False))
+        with jax.named_scope(STAGE_INVERSE):
+            any_flag = functools.reduce(
+                jnp.logical_or, [flags[f"{fam}.{k}"] for k in raw],
+                jnp.asarray(False))
 
-        # which stats carry per-block inversion diagnostics: the full-kind
-        # a/g factors (static set — the cond's branch trees must match)
-        want_info = cfg.inverse_info
-        info_keys = [k for k in ("a", "g") if k in normalized and
-                     (info.spec.a_kind if k == "a" else
-                      info.spec.g_kind) == "full"] if want_info else []
+            # which stats carry per-block inversion diagnostics: the full-kind
+            # a/g factors (static set — the cond's branch trees must match)
+            want_info = cfg.inverse_info
+            info_keys = [k for k in ("a", "g") if k in normalized and
+                         (info.spec.a_kind if k == "a" else
+                          info.spec.g_kind) == "full"] if want_info else []
 
-        def recompute(_):
-            from repro.obs.tracing import STAGE_INVERSE
-            pc, inv_info = {}, {}
-            if "a" in normalized or "g" in normalized:
-                a = normalized.get("a")
-                g = normalized.get("g")
-                if a is not None and g is not None:
-                    ea = _mean_eig(a, info.spec.a_kind, info.d_in)
-                    eg = _mean_eig(g, info.spec.g_kind, info.d_out)
-                    pi = jnp.sqrt(jnp.maximum(ea, 1e-12) / jnp.maximum(eg, 1e-12))
-                else:
-                    pi = jnp.ones(a.shape[:len(info.lead)] if a is not None
-                                  else g.shape[:len(info.lead)])
-                sl = jnp.sqrt(jnp.asarray(lam, jnp.float32))
-                with jax.named_scope(STAGE_INVERSE):
-                    for key, stat, d in (("a", a, pi * sl), ("g", g, sl / pi)):
+            def recompute(_):
+                pc, inv_info = {}, {}
+                if "a" in normalized or "g" in normalized:
+                    a = normalized.get("a")
+                    g = normalized.get("g")
+                    if a is not None and g is not None:
+                        ea = _mean_eig(a, info.spec.a_kind, info.d_in)
+                        eg = _mean_eig(g, info.spec.g_kind, info.d_out)
+                        pi = jnp.sqrt(jnp.maximum(ea, 1e-12)
+                                      / jnp.maximum(eg, 1e-12))
+                    else:
+                        pi = jnp.ones(a.shape[:len(info.lead)] if a is not None
+                                      else g.shape[:len(info.lead)])
+                    sl = jnp.sqrt(jnp.asarray(lam, jnp.float32))
+                    for key, stat, d in (("a", a, pi * sl),
+                                         ("g", g, sl / pi)):
                         if stat is None:
                             continue
                         kind = (info.spec.a_kind if key == "a"
@@ -425,28 +431,30 @@ class SPNGD:
                         else:
                             pc[key] = self._stat_inverse(fam, key, stat,
                                                          kind, d)
-            for key in ("d", "uw"):
-                if key in normalized:
-                    pc[key] = normalized[key]
-            if "uwf" in normalized:
-                # full BN Fisher (2C x 2C): invert directly with lam damping
-                pc["uwf"] = kfac.damped_inverse(
-                    normalized["uwf"], jnp.asarray(lam, jnp.float32))
-            return pc, inv_info
+                for key in ("d", "uw"):
+                    if key in normalized:
+                        pc[key] = normalized[key]
+                if "uwf" in normalized:
+                    # full BN Fisher (2C x 2C): invert directly with lam
+                    # damping
+                    pc["uwf"] = kfac.damped_inverse(
+                        normalized["uwf"], jnp.asarray(lam, jnp.float32))
+                return pc, inv_info
 
-        def keep(_):
-            # not-refreshed sentinels: ns_res=-1 (no inversion ran this
-            # step), converged=True — shape-matched to recompute's info so
-            # the cond branches return identical pytrees
-            inv_info = {k: {"ns_res": jnp.full(normalized[k].shape[:-2],
-                                               -1.0, jnp.float32),
-                            "ns_converged": jnp.full(
-                                normalized[k].shape[:-2], True)}
-                        for k in info_keys}
-            return curv["precond_next" if cfg.double_buffer else "precond"], \
-                inv_info
+            def keep(_):
+                # not-refreshed sentinels: ns_res=-1 (no inversion ran
+                # this step), converged=True — shape-matched to recompute's
+                # info so the cond branches return identical pytrees
+                inv_info = {k: {"ns_res": jnp.full(normalized[k].shape[:-2],
+                                                   -1.0, jnp.float32),
+                                "ns_converged": jnp.full(
+                                    normalized[k].shape[:-2], True)}
+                            for k in info_keys}
+                kept = curv["precond_next" if cfg.double_buffer
+                            else "precond"]
+                return kept, inv_info
 
-        precond, inv_info = jax.lax.cond(any_flag, recompute, keep, None)
+            precond, inv_info = jax.lax.cond(any_flag, recompute, keep, None)
         if cfg.double_buffer:
             # pipeline: the fresh inverses are STAGED (precond_next) and the
             # buffer staged by the latest earlier refresh activates for this
@@ -531,7 +539,6 @@ class SPNGD:
     def _finish(self, params, state, grads, curv, lam, lr, mom, loss, aux,
                 sims, inverse_info: Optional[dict] = None,
                 extra_metrics: Optional[dict] = None):
-        from repro.obs.tracing import STAGE_PRECOND
         cfg = self.cfg
         # preconditioned updates for sited params
         updates = {}
@@ -539,52 +546,57 @@ class SPNGD:
             for fam, c in curv.items():
                 updates.update(self._apply_precond(fam, grads, c, lam))
 
-        sited = set(updates)
+        # the first-order fallback, the norms, momentum and the parameter
+        # update: disjoint from the preconditioning above
+        with jax.named_scope(STAGE_UPDATE):
+            sited = set(updates)
 
-        def leaf_update(path_str, g):
-            if path_str in updates:
-                return updates[path_str]
-            return g * cfg.sgd_fallback_scale     # non-sited: first-order
+            def leaf_update(path_str, g):
+                if path_str in updates:
+                    return updates[path_str]
+                return g * cfg.sgd_fallback_scale     # non-sited: first-order
 
-        flat_g = _flatten_paths(grads)
-        flat_p = _flatten_paths(params)
-        flat_v = _flatten_paths(state["velocity"])
-        new_p, new_v = {}, {}
-        gsq = usq = jnp.zeros((), jnp.float32)
-        for path_str, g in flat_g.items():
-            u = leaf_update(path_str, g)
-            gsq += jnp.sum(jnp.square(g.astype(jnp.float32)))
-            usq += jnp.sum(jnp.square(u.astype(jnp.float32)))
-            v_dtype = flat_v[path_str].dtype
-            # strongly typed f32 lr/mom would promote a bf16 velocity: keep
-            # its storage dtype so the donated state buffers alias in place
-            v = (mom * flat_v[path_str] - lr * u.astype(v_dtype)
-                 ).astype(v_dtype)
-            w = flat_p[path_str] + v.astype(flat_p[path_str].dtype)
-            new_v[path_str] = v
-            new_p[path_str] = w
+            flat_g = _flatten_paths(grads)
+            flat_p = _flatten_paths(params)
+            flat_v = _flatten_paths(state["velocity"])
+            new_p, new_v = {}, {}
+            gsq = usq = jnp.zeros((), jnp.float32)
+            for path_str, g in flat_g.items():
+                u = leaf_update(path_str, g)
+                gsq += jnp.sum(jnp.square(g.astype(jnp.float32)))
+                usq += jnp.sum(jnp.square(u.astype(jnp.float32)))
+                v_dtype = flat_v[path_str].dtype
+                # strongly typed f32 lr/mom would promote a bf16 velocity: keep
+                # its storage dtype so the donated state buffers alias in place
+                v = (mom * flat_v[path_str] - lr * u.astype(v_dtype)
+                     ).astype(v_dtype)
+                w = flat_p[path_str] + v.astype(flat_p[path_str].dtype)
+                new_v[path_str] = v
+                new_p[path_str] = w
 
-        # Eq. 24 weight rescaling on dense/conv/grouped weights
-        if cfg.weight_rescale:
-            for fam, info in self.infos.items():
-                if info.kind in ("dense", "conv", "grouped"):
-                    w = new_p[info.param]
-                    naxes = 2 if info.kind in ("dense", "grouped") else 4
-                    axes = tuple(range(w.ndim - naxes, w.ndim))
-                    norm = jnp.sqrt(jnp.sum(w.astype(jnp.float32) ** 2, axis=axes,
-                                            keepdims=True))
-                    target = jnp.sqrt(2.0 * info.d_out)
-                    new_p[info.param] = (w * (target / (norm + cfg.rescale_eps))
-                                         ).astype(w.dtype)
+            # Eq. 24 weight rescaling on dense/conv/grouped weights
+            if cfg.weight_rescale:
+                for fam, info in self.infos.items():
+                    if info.kind in ("dense", "conv", "grouped"):
+                        w = new_p[info.param]
+                        naxes = 2 if info.kind in ("dense", "grouped") else 4
+                        axes = tuple(range(w.ndim - naxes, w.ndim))
+                        norm = jnp.sqrt(jnp.sum(w.astype(jnp.float32) ** 2,
+                                                axis=axes, keepdims=True))
+                        target = jnp.sqrt(2.0 * info.d_out)
+                        new_p[info.param] = (
+                            w * (target / (norm + cfg.rescale_eps))
+                        ).astype(w.dtype)
 
-        params_out = _unflatten_paths(new_p, like=params)
-        vel_out = _unflatten_paths(new_v, like=params)
-        # spread: auxiliary state (e.g. the refresh pipeline's cursor/raw
-        # store, already advanced by the caller) rides through unchanged
-        state_out = {**state, "step": state["step"] + 1, "velocity": vel_out,
-                     "curv": curv}
+            params_out = _unflatten_paths(new_p, like=params)
+            vel_out = _unflatten_paths(new_v, like=params)
+            # spread: auxiliary state (e.g. the refresh pipeline's cursor/raw
+            # store, already advanced by the caller) rides through unchanged
+            state_out = {**state, "step": state["step"] + 1,
+                         "velocity": vel_out, "curv": curv}
+            grad_norm, update_norm = jnp.sqrt(gsq), jnp.sqrt(usq)
         metrics = {"loss": loss, "sims": sims,
-                   "grad_norm": jnp.sqrt(gsq), "update_norm": jnp.sqrt(usq)}
+                   "grad_norm": grad_norm, "update_norm": update_norm}
         if inverse_info:
             metrics["inverse_info"] = inverse_info
         if extra_metrics:
@@ -599,9 +611,8 @@ class SPNGD:
         """One backward pass: (loss, aux, grads, raw factor sums). Exposed
         separately so the launch layer can accumulate over microbatches —
         the paper's own method for mimicking BS=65K/131K (§7.1)."""
-        from repro.obs.tracing import STAGE_CAPTURE
-        fstats = self.fstats_fn()
-        with jax.named_scope(STAGE_CAPTURE):
+        with jax.named_scope(STAGE_CAPTURE), jax.named_scope(STAGE_FWD_BWD):
+            fstats = self.fstats_fn()
             if self.cfg.estimator == "1mc":
                 return mc_fisher_grads(self.loss_fn, params, fstats, batch,
                                        rng)
@@ -648,15 +659,17 @@ class SPNGD:
             curv[fam] = {**curv_in[fam], "prev": new_prev,
                          "prev2": new_prev2}
             new_raw[fam] = normalized
-            new_valid[fam] = {
-                k: jnp.logical_or(pipe["valid"][fam][k],
-                                  flags[f"{fam}.{k}"])
-                for k in raw[fam]}
-        pipe = {"cursor": jnp.zeros((), jnp.int32), "raw": new_raw,
-                "valid": new_valid}
+        with jax.named_scope(STAGE_DRAIN):
+            for fam in raw:
+                new_valid[fam] = {
+                    k: jnp.logical_or(pipe["valid"][fam][k],
+                                      flags[f"{fam}.{k}"])
+                    for k in raw[fam]}
+            pipe = {"cursor": jnp.zeros((), jnp.int32), "raw": new_raw,
+                    "valid": new_valid}
+            extra = {"refresh_inflight": jnp.asarray(
+                self.pipeline.chunks + 1, jnp.int32)}
         state = {**state, "pipeline": pipe}
-        extra = {"refresh_inflight": jnp.asarray(
-            self.pipeline.chunks + 1, jnp.int32)}
         return self._finish(params, state, grads, curv, lam, lr, mom,
                             loss, aux, sims, extra_metrics=extra)
 
@@ -679,15 +692,17 @@ class SPNGD:
         """Full step with curvature capture. ``flags`` maps stat_name ->
         bool (traced ok)."""
         loss, aux, grads, raw = self.grads_and_raw(params, batch, rng)
-        counts = self.counts_fn(batch)
+        with jax.named_scope(STAGE_STATS):
+            counts = self.counts_fn(batch)
         return self.apply_update(params, state, grads, raw, counts, flags,
                                  lam, lr, mom, loss, aux)
 
     def step_fast(self, params, state, batch, lam, lr, mom):
         """No capture, no refresh: backward + stale-preconditioned update
         (plus one pipeline drain chunk when ``refresh_chunks > 1``)."""
-        (loss, aux), grads = jax.value_and_grad(
-            self.loss_fn, has_aux=True)(params, None, batch)
+        with jax.named_scope(STAGE_FWD_BWD):
+            (loss, aux), grads = jax.value_and_grad(
+                self.loss_fn, has_aux=True)(params, None, batch)
         state, curv, extra = self.fast_curv(state, lam)
         return self._finish(params, state, grads, curv, lam, lr, mom,
                             loss, aux, {}, extra_metrics=extra)

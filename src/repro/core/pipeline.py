@@ -60,6 +60,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import kfac
+from repro.obs.tracing import STAGE_CHUNK, STAGE_DRAIN, STAGE_FLIP
 
 
 def _unit_cost(shape: tuple, kind: str) -> int:
@@ -137,6 +138,7 @@ class RefreshPipeline:
 
     # ---- traced entry points ----
 
+    @jax.named_scope(STAGE_FLIP)
     def flip(self, curv: dict, pipe: dict) -> dict:
         """Activate a completed drain: when ``cursor == K`` every valid
         statistic's ``precond_next`` becomes ``precond`` (atomic per stat —
@@ -162,26 +164,27 @@ class RefreshPipeline:
         drain-time damping; under the stock schedules lambda is constant
         over a run, so it equals the capture-time value.
         """
-        from repro.obs.tracing import STAGE_CHUNK
         k = self.chunks
-        cursor = pipe["cursor"]
         curv = self.flip(curv, pipe)
-        pnext = {fam: entry["precond_next"] for fam, entry in curv.items()}
+        with jax.named_scope(STAGE_DRAIN):
+            cursor = pipe["cursor"]
+            pnext = {fam: entry["precond_next"]
+                     for fam, entry in curv.items()}
 
-        def wrap(i, fn):
-            def branch(op):
-                with jax.named_scope(f"{STAGE_CHUNK}[{i}/{k}]"):
-                    return fn(*op)
-            return branch
+            def wrap(i, fn):
+                def branch(op):
+                    with jax.named_scope(f"{STAGE_CHUNK}[{i}/{k}]"):
+                        return fn(*op)
+                return branch
 
-        branches = [wrap(i, self._chunk_fn(i)) for i in range(k)]
-        branches.append(lambda op: op[0])          # idle / flip-step no-op
-        pnext = jax.lax.switch(jnp.minimum(cursor, k), branches,
-                               (pnext, pipe["raw"], lam))
-        curv = {fam: {**entry, "precond_next": pnext[fam]}
-                for fam, entry in curv.items()}
-        inflight = jnp.clip(k + 1 - cursor, 0, k + 1).astype(jnp.int32)
-        pipe = {**pipe, "cursor": jnp.minimum(cursor + 1, k + 1)}
+            branches = [wrap(i, self._chunk_fn(i)) for i in range(k)]
+            branches.append(lambda op: op[0])      # idle / flip-step no-op
+            pnext = jax.lax.switch(jnp.minimum(cursor, k), branches,
+                                   (pnext, pipe["raw"], lam))
+            curv = {fam: {**entry, "precond_next": pnext[fam]}
+                    for fam, entry in curv.items()}
+            inflight = jnp.clip(k + 1 - cursor, 0, k + 1).astype(jnp.int32)
+            pipe = {**pipe, "cursor": jnp.minimum(cursor + 1, k + 1)}
         return curv, pipe, inflight
 
     # ---- chunk bodies ----

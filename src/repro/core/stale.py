@@ -34,6 +34,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
+from repro.obs.tracing import HOST_CONTROLLER, Span
+
 
 @dataclasses.dataclass
 class StatState:
@@ -117,37 +119,38 @@ class IntervalController:
         sims[name] = (dist_to_prev, dist_to_prev2); entries for statistics
         that did not refresh are ignored.
         """
-        self.steps += 1
-        for name, st in self.stats.items():
-            self.dense_bytes += st.bytes_per_refresh
-            self.dense_wire_bytes += st.wire_bytes_per_refresh
-            self.dense_wire_intra_bytes += st.wire_intra_bytes_per_refresh
-            self.dense_wire_inter_bytes += st.wire_inter_bytes_per_refresh
-            self.dense_gather_bytes += st.gather_bytes_per_refresh
-            if not flags.get(name, False):
-                continue
-            d1, d2 = sims[name]
-            # Algorithm 2: shrink/fall-back compute from the PREVIOUS
-            # interval Δ₋₁ (st.delta_m1), not the just-elapsed st.delta —
-            # growth is tentative until the similarity check validates it
-            if d1 >= self.alpha:
-                delta = max(1, st.delta_m1 // 2)
-            elif d2 >= self.alpha:
-                delta = st.delta_m1
-            else:
-                delta = st.delta + st.delta_m1
-            delta = max(delta, self.min_interval)
-            if self.max_interval:
-                delta = min(delta, self.max_interval)
-            st.delta_m1 = st.delta
-            st.delta = delta
-            st.t_next = t + delta
-            st.refresh_count += 1
-            self.total_bytes += st.bytes_per_refresh
-            self.total_wire_bytes += st.wire_bytes_per_refresh
-            self.total_wire_intra_bytes += st.wire_intra_bytes_per_refresh
-            self.total_wire_inter_bytes += st.wire_inter_bytes_per_refresh
-            self.total_gather_bytes += st.gather_bytes_per_refresh
+        with Span(HOST_CONTROLLER):
+            self.steps += 1
+            for name, st in self.stats.items():
+                self.dense_bytes += st.bytes_per_refresh
+                self.dense_wire_bytes += st.wire_bytes_per_refresh
+                self.dense_wire_intra_bytes += st.wire_intra_bytes_per_refresh
+                self.dense_wire_inter_bytes += st.wire_inter_bytes_per_refresh
+                self.dense_gather_bytes += st.gather_bytes_per_refresh
+                if not flags.get(name, False):
+                    continue
+                d1, d2 = sims[name]
+                # Algorithm 2: shrink/fall-back compute from the PREVIOUS
+                # interval Δ₋₁ (st.delta_m1), not the just-elapsed st.delta —
+                # growth is tentative until the similarity check validates it
+                if d1 >= self.alpha:
+                    delta = max(1, st.delta_m1 // 2)
+                elif d2 >= self.alpha:
+                    delta = st.delta_m1
+                else:
+                    delta = st.delta + st.delta_m1
+                delta = max(delta, self.min_interval)
+                if self.max_interval:
+                    delta = min(delta, self.max_interval)
+                st.delta_m1 = st.delta
+                st.delta = delta
+                st.t_next = t + delta
+                st.refresh_count += 1
+                self.total_bytes += st.bytes_per_refresh
+                self.total_wire_bytes += st.wire_bytes_per_refresh
+                self.total_wire_intra_bytes += st.wire_intra_bytes_per_refresh
+                self.total_wire_inter_bytes += st.wire_inter_bytes_per_refresh
+                self.total_gather_bytes += st.gather_bytes_per_refresh
 
     # ---- Stage-3 comm bookkeeping (repro.comm reducer tally) ----
 

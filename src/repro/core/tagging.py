@@ -37,6 +37,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from repro.core import kfac
+from repro.obs.tracing import STAGE_STATS
 
 
 # ---------------------------------------------------------------------------
@@ -138,6 +139,7 @@ def _acc_shape(acc):
     return acc.shape
 
 
+@jax.named_scope(STAGE_STATS)
 def _stat_sum(x2d: jax.Array, kind: str, max_dim: int,
               want_shape, backend: str = "auto",
               spec: Optional[FactorSpec] = None):
@@ -262,7 +264,8 @@ def _bias_site_bwd(res, gy):
     (b_shape,) = res
     g2d = gy.reshape(-1, b_shape[-1]).astype(jnp.float32)
     db = g2d.sum(0).astype(jnp.float32)
-    dacc = jnp.sum(g2d * g2d, axis=0)
+    with jax.named_scope(STAGE_STATS):
+        dacc = jnp.sum(g2d * g2d, axis=0)
     return gy, db, dacc
 
 
@@ -315,16 +318,20 @@ def _scale_bias_site_bwd(spatial, has_beta, res, gy):
     vs2 = vs.reshape(-1, c)
     dgamma = us2.sum(0)
     dbeta = vs2.sum(0)
-    if len(acc_shape) >= 2 and acc_shape[-1] == 2 * c:
-        # FULL BN Fisher (2C x 2C) — the paper's expensive baseline (Fig. 5
-        # "fullBN"): outer products of the concatenated per-sample grads.
-        z = jnp.concatenate([us2, vs2], axis=-1)  # (n, 2C)
-        dacc = (z.T @ z).reshape(acc_shape)
-    else:
-        # unit-wise stats (C, 3): [sum u^2, sum u v, sum v^2] (Eq. 15-16)
-        dacc = jnp.stack([jnp.sum(us2 * us2, 0),
-                          jnp.sum(us2 * vs2, 0),
-                          jnp.sum(vs2 * vs2, 0)], axis=-1).reshape(acc_shape)
+    with jax.named_scope(STAGE_STATS):
+        if len(acc_shape) >= 2 and acc_shape[-1] == 2 * c:
+            # FULL BN Fisher (2C x 2C) — the paper's expensive baseline
+            # (Fig. 5 "fullBN"): outer products of the concatenated
+            # per-sample grads.
+            z = jnp.concatenate([us2, vs2], axis=-1)  # (n, 2C)
+            dacc = (z.T @ z).reshape(acc_shape)
+        else:
+            # unit-wise stats (C, 3): [sum u^2, sum u v, sum v^2]
+            # (Eq. 15-16)
+            dacc = jnp.stack([jnp.sum(us2 * us2, 0),
+                              jnp.sum(us2 * vs2, 0),
+                              jnp.sum(vs2 * vs2, 0)],
+                             axis=-1).reshape(acc_shape)
     dx = (gf * gamma).astype(xhat.dtype)
     if not has_beta:
         dbeta = jnp.zeros_like(dbeta)
@@ -374,7 +381,9 @@ def _embed_site_bwd(spec, res, gy):
     flat_ids = ids.reshape(-1)
     g2d = gy.reshape(-1, d)
     dtable = jnp.zeros(tshape, gy.dtype).at[flat_ids].add(g2d)
-    da = jnp.zeros(a_shape, jnp.float32).at[flat_ids].add(1.0) if a_shape else jnp.zeros(a_shape)
+    with jax.named_scope(STAGE_STATS):
+        da = (jnp.zeros(a_shape, jnp.float32).at[flat_ids].add(1.0)
+              if a_shape else jnp.zeros(a_shape))
     dg = (_stat_sum(g2d, spec.g_kind, spec.g_dim, g_shape, spec.backend,
                     spec)
           if g_shape else jnp.zeros(g_shape))

@@ -19,7 +19,12 @@ REPO_CACHE_DIR = os.path.join(
 
 
 def use_compile_cache() -> str:
-    """Turn the persistent compilation cache on; returns its directory."""
+    """Turn the persistent compilation cache on; returns its directory.
+
+    The key includes the programs' HLO metadata (JAX strips it by
+    default), so a program whose ``jax.named_scope`` s changed compiles
+    anew and a profiler trace names the scopes of the source that ran."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not path:
         path = REPO_CACHE_DIR

@@ -21,6 +21,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.ngd import SPNGD
+from repro.obs.tracing import (HOST_DISPATCH, HOST_FLAGS, HOST_SIMS,
+                               HOST_STEP, STAGE_CAPTURE, STAGE_FWD_BWD,
+                               STAGE_REDUCE, STAGE_STATS, Span)
 
 
 def _check_accum_capture(opt: SPNGD, accum: int) -> None:
@@ -46,36 +49,39 @@ def make_train_step(model, opt: SPNGD, accum: int = 1) -> Callable:
     _check_accum_capture(opt, accum)
 
     def train_step(params, opt_state, batch, flags, lam, lr, mom):
-        counts = model.site_counts(batch)          # full-batch counts
+        with jax.named_scope(STAGE_STATS):
+            counts = model.site_counts(batch)      # full-batch counts
 
         if accum == 1:
             loss, aux, grads, raw = opt.grads_and_raw(params, batch)
             loss_mean = loss
         else:
-            micro = jax.tree.map(
-                lambda x: x.reshape((accum, x.shape[0] // accum) + x.shape[1:]),
-                batch)
-            mb0 = jax.tree.map(lambda x: x[0], micro)
-            g_shape = jax.eval_shape(opt.grads_and_raw, params, mb0)
-            zeros = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
-                                 (g_shape[2], g_shape[3]))
+            with jax.named_scope(STAGE_CAPTURE):
+                micro = jax.tree.map(
+                    lambda x: x.reshape((accum, x.shape[0] // accum)
+                                        + x.shape[1:]), batch)
+                mb0 = jax.tree.map(lambda x: x[0], micro)
+                g_shape = jax.eval_shape(opt.grads_and_raw, params, mb0)
+                zeros = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                                     (g_shape[2], g_shape[3]))
 
-            def body(carry, mb):
-                g_acc, r_acc, l_acc = carry
-                loss, aux, g, r = opt.grads_and_raw(params, mb)
-                g_acc = jax.tree.map(jnp.add, g_acc, g)
-                r_acc = jax.tree.map(jnp.add, r_acc, r)
-                return (g_acc, r_acc, l_acc + loss), None
+                def body(carry, mb):
+                    g_acc, r_acc, l_acc = carry
+                    loss, aux, g, r = opt.grads_and_raw(params, mb)
+                    g_acc = jax.tree.map(jnp.add, g_acc, g)
+                    r_acc = jax.tree.map(jnp.add, r_acc, r)
+                    return (g_acc, r_acc, l_acc + loss), None
 
-            (grads, raw, loss_sum), _ = jax.lax.scan(
-                body, (zeros[0], zeros[1], jnp.zeros((), jnp.float32)), micro)
-            grads = jax.tree.map(lambda g: g / accum, grads)
-            # G-type raw sums: undo the microbatch mean-loss scaling
-            raw = {fam: {k: (v if k == "a" else v / (accum * accum))
-                         for k, v in stats.items()}
-                   for fam, stats in raw.items()}
-            loss_mean = loss_sum / accum
-            aux = {}
+                (grads, raw, loss_sum), _ = jax.lax.scan(
+                    body, (zeros[0], zeros[1], jnp.zeros((), jnp.float32)),
+                    micro)
+                grads = jax.tree.map(lambda g: g / accum, grads)
+                # G-type raw sums: undo the microbatch mean-loss scaling
+                raw = {fam: {k: (v if k == "a" else v / (accum * accum))
+                             for k, v in stats.items()}
+                       for fam, stats in raw.items()}
+                loss_mean = loss_sum / accum
+                aux = {}
 
         return opt.apply_update(params, opt_state, grads, raw, counts,
                                 flags, lam, lr, mom, loss_mean, aux)
@@ -88,20 +94,22 @@ def make_fast_step(model, opt: SPNGD, accum: int = 1) -> Callable:
     def fast_step(params, opt_state, batch, lam, lr, mom):
         if accum == 1:
             return opt.step_fast(params, opt_state, batch, lam, lr, mom)
-        micro = jax.tree.map(
-            lambda x: x.reshape((accum, x.shape[0] // accum) + x.shape[1:]),
-            batch)
+        with jax.named_scope(STAGE_FWD_BWD):
+            micro = jax.tree.map(
+                lambda x: x.reshape((accum, x.shape[0] // accum)
+                                    + x.shape[1:]), batch)
 
-        def body(carry, mb):
-            g_acc, l_acc = carry
-            (loss, aux), g = jax.value_and_grad(
-                opt.loss_fn, has_aux=True)(params, None, mb)
-            return (jax.tree.map(jnp.add, g_acc, g), l_acc + loss), None
+            def body(carry, mb):
+                g_acc, l_acc = carry
+                (loss, aux), g = jax.value_and_grad(
+                    opt.loss_fn, has_aux=True)(params, None, mb)
+                return (jax.tree.map(jnp.add, g_acc, g), l_acc + loss), None
 
-        zeros = jax.tree.map(lambda x: jnp.zeros(x.shape, x.dtype), params)
-        (grads, loss_sum), _ = jax.lax.scan(
-            body, (zeros, jnp.zeros((), jnp.float32)), micro)
-        grads = jax.tree.map(lambda g: g / accum, grads)
+            zeros = jax.tree.map(lambda x: jnp.zeros(x.shape, x.dtype),
+                                 params)
+            (grads, loss_sum), _ = jax.lax.scan(
+                body, (zeros, jnp.zeros((), jnp.float32)), micro)
+            grads = jax.tree.map(lambda g: g / accum, grads)
         opt_state, curv, extra = opt.fast_curv(opt_state, lam)
         return opt._finish(params, opt_state, grads, curv,
                            lam, lr, mom, loss_sum / accum, {}, {},
@@ -158,48 +166,52 @@ def make_shardmap_train_step(model, opt: SPNGD, mesh, accum: int = 1,
             loss, aux, grads, raw = opt.grads_and_raw(params, batch)
             loss_sum = loss
         else:
-            micro = jax.tree.map(
-                lambda x: x.reshape((accum, x.shape[0] // accum)
-                                    + x.shape[1:]), batch)
-            mb0 = jax.tree.map(lambda x: x[0], micro)
-            g_shape = jax.eval_shape(opt.grads_and_raw, params, mb0)
-            zeros = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
-                                 (g_shape[2], g_shape[3]))
+            with jax.named_scope(STAGE_CAPTURE):
+                micro = jax.tree.map(
+                    lambda x: x.reshape((accum, x.shape[0] // accum)
+                                        + x.shape[1:]), batch)
+                mb0 = jax.tree.map(lambda x: x[0], micro)
+                g_shape = jax.eval_shape(opt.grads_and_raw, params, mb0)
+                zeros = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                                     (g_shape[2], g_shape[3]))
 
-            def body(carry, mb):
-                g_acc, r_acc, l_acc = carry
-                loss, aux, g, r = opt.grads_and_raw(params, mb)
-                return (jax.tree.map(jnp.add, g_acc, g),
-                        jax.tree.map(jnp.add, r_acc, r),
-                        l_acc + loss), None
+                def body(carry, mb):
+                    g_acc, r_acc, l_acc = carry
+                    loss, aux, g, r = opt.grads_and_raw(params, mb)
+                    return (jax.tree.map(jnp.add, g_acc, g),
+                            jax.tree.map(jnp.add, r_acc, r),
+                            l_acc + loss), None
 
-            (grads, raw, loss_sum), _ = jax.lax.scan(
-                body, (zeros[0], zeros[1], jnp.zeros((), jnp.float32)), micro)
+                (grads, raw, loss_sum), _ = jax.lax.scan(
+                    body, (zeros[0], zeros[1], jnp.zeros((), jnp.float32)),
+                    micro)
 
         # ---- Stage 3: explicit collectives, once per step ----
-        loss = reducer.psum(loss_sum) / (ndev * accum)
-        grads = jax.tree.map(lambda g: reducer.psum(g) / (ndev * accum),
-                             grads)
-        g_scale = 1.0 / (accum * accum * ndev * ndev)
-        # undo local-mean-loss scaling BEFORE the reduce (the fp8 wire
-        # quantizes what actually travels). Fused wire-format capture
-        # already quantized the payload — rescale its per-block scales
-        # instead, which is mathematically exact.
-        from repro import quant
+        with jax.named_scope(STAGE_REDUCE):
+            loss = reducer.psum(loss_sum) / (ndev * accum)
+            grads = jax.tree.map(lambda g: reducer.psum(g) / (ndev * accum),
+                                 grads)
+            g_scale = 1.0 / (accum * accum * ndev * ndev)
+            # undo local-mean-loss scaling BEFORE the reduce (the fp8 wire
+            # quantizes what actually travels). Fused wire-format capture
+            # already quantized the payload — rescale its per-block scales
+            # instead, which is mathematically exact.
+            from repro import quant
 
-        def _rescale_g(v):
-            if quant.is_wire(v):
-                return {"payload": v["payload"],
-                        "scale": v["scale"] * g_scale}
-            return v * g_scale
+            def _rescale_g(v):
+                if quant.is_wire(v):
+                    return {"payload": v["payload"],
+                            "scale": v["scale"] * g_scale}
+                return v * g_scale
 
-        raw = {fam: {k: (v if k == "a" else _rescale_g(v))
-                     for k, v in stats.items()}
-               for fam, stats in raw.items()}
+            raw = {fam: {k: (v if k == "a" else _rescale_g(v))
+                         for k, v in stats.items()}
+                   for fam, stats in raw.items()}
         return loss, grads, reducer.reduce(raw)
 
     def train_step(params, opt_state, batch, flags, lam, lr, mom):
-        counts = model.site_counts(batch)
+        with jax.named_scope(STAGE_STATS):
+            counts = model.site_counts(batch)
         batch_specs = jax.tree.map(
             lambda x: P(dp, *(None,) * (x.ndim - 1)), batch)
         sm = jax.shard_map(
@@ -230,27 +242,31 @@ def make_shardmap_fast_step(model, opt: SPNGD, mesh, accum: int = 1,
     dp, ndev = reducer.dp, reducer.ndev
 
     def inner(params, batch):
-        if accum == 1:
-            (loss, aux), grads = jax.value_and_grad(
-                opt.loss_fn, has_aux=True)(params, None, batch)
-            loss_sum = loss
-        else:
-            micro = jax.tree.map(
-                lambda x: x.reshape((accum, x.shape[0] // accum)
-                                    + x.shape[1:]), batch)
+        with jax.named_scope(STAGE_FWD_BWD):
+            if accum == 1:
+                (loss, aux), grads = jax.value_and_grad(
+                    opt.loss_fn, has_aux=True)(params, None, batch)
+                loss_sum = loss
+            else:
+                micro = jax.tree.map(
+                    lambda x: x.reshape((accum, x.shape[0] // accum)
+                                        + x.shape[1:]), batch)
 
-            def body(carry, mb):
-                g_acc, l_acc = carry
-                (loss, aux), g = jax.value_and_grad(
-                    opt.loss_fn, has_aux=True)(params, None, mb)
-                return (jax.tree.map(jnp.add, g_acc, g), l_acc + loss), None
+                def body(carry, mb):
+                    g_acc, l_acc = carry
+                    (loss, aux), g = jax.value_and_grad(
+                        opt.loss_fn, has_aux=True)(params, None, mb)
+                    return (jax.tree.map(jnp.add, g_acc, g),
+                            l_acc + loss), None
 
-            zeros = jax.tree.map(lambda x: jnp.zeros(x.shape, x.dtype), params)
-            (grads, loss_sum), _ = jax.lax.scan(
-                body, (zeros, jnp.zeros((), jnp.float32)), micro)
-        loss = reducer.psum(loss_sum) / (ndev * accum)
-        grads = jax.tree.map(lambda g: reducer.psum(g) / (ndev * accum),
-                             grads)
+                zeros = jax.tree.map(lambda x: jnp.zeros(x.shape, x.dtype),
+                                     params)
+                (grads, loss_sum), _ = jax.lax.scan(
+                    body, (zeros, jnp.zeros((), jnp.float32)), micro)
+        with jax.named_scope(STAGE_REDUCE):
+            loss = reducer.psum(loss_sum) / (ndev * accum)
+            grads = jax.tree.map(lambda g: reducer.psum(g) / (ndev * accum),
+                                 grads)
         return loss, grads
 
     def fast_step(params, opt_state, batch, lam, lr, mom):
@@ -285,6 +301,47 @@ def make_prefill_step(model) -> Callable:
         logits, _ = model.forward(params, batch)
         return logits
     return prefill_step
+
+
+# ---------------------------------------------------------------------------
+# one training step, as the host drives it
+# ---------------------------------------------------------------------------
+
+def take_step(step_j, fast_j, ctrl, t, params, state, batch, lam, lr, mom):
+    """Step ``t`` of the training loop: the controller's flags pick the
+    program; a capture step (``step_j``, flags on the device) reads back
+    each statistic's two similarities for the controller, a fast step
+    (``fast_j``) passes none. Returns ``(params, state, metrics, flags)``.
+
+    Each host phase runs under a :class:`~repro.obs.tracing.Span`, so a
+    profiler trace names what the host does while the device idles:
+    ``spngd.host.step`` (the whole step; ``kind`` capture or fast),
+    ``spngd.host.flags`` (``n`` flags moved to the device),
+    ``spngd.host.dispatch`` (the call of the jitted program) and
+    ``spngd.host.sims`` (``n`` scalars read back: the host waits for the
+    capture here); ``IntervalController.update`` opens
+    ``spngd.host.controller`` itself."""
+    with Span(HOST_STEP, step_num=t) as step:
+        with Span(HOST_FLAGS) as moved:
+            flags = ctrl.flags(t)
+            capture = any(flags.values())
+            jflags = ({k: jnp.asarray(v) for k, v in flags.items()}
+                      if capture else {})
+            moved.set(n=len(jflags))
+        step.set(kind="capture" if capture else "fast")
+        if capture:
+            with Span(HOST_DISPATCH):
+                params, state, m = step_j(params, state, batch, jflags,
+                                          lam, lr, mom)
+            with Span(HOST_SIMS, n=2 * len(m["sims"])):
+                sims = {k: (float(v[0]), float(v[1]))
+                        for k, v in m["sims"].items()}
+            ctrl.update(t, flags, sims)
+        else:
+            with Span(HOST_DISPATCH):
+                params, state, m = fast_j(params, state, batch, lam, lr, mom)
+            ctrl.update(t, flags, {})
+    return params, state, m, flags
 
 
 # ---------------------------------------------------------------------------
@@ -462,12 +519,16 @@ def build_parser():
                          "Console text is unchanged (and mirrored into the "
                          "stream); disabled = zero-cost no-op")
     ap.add_argument("--profile-dir", default=None, metavar="DIR",
-                    help="capture a jax.profiler trace of the first "
-                         "--profile-steps steps into DIR (stage scopes "
-                         "spngd.stage*.* and kernel scopes "
-                         "repro.kernels.*[backend] name the regions)")
+                    help="capture a jax.profiler trace of whole refresh "
+                         "cycles into DIR, from the first capture step "
+                         "after the first refresh has activated (both step "
+                         "programs compiled) to a capture boundary. Device "
+                         "scopes spngd.* and repro.kernels.*[backend] name "
+                         "the ops; host spans spngd.host.* name each "
+                         "step's host phases")
     ap.add_argument("--profile-steps", type=int, default=3,
-                    help="length of the --profile-dir capture window")
+                    help="the --profile-dir window holds at least this "
+                         "many steps and ends on the next capture step")
     ap.add_argument("--no-overhead-probe", action="store_true",
                     help="skip the stage-isolated timing probe that "
                          "metrics-enabled runs emit for make_report.py's "
@@ -579,26 +640,23 @@ def run(cfg, args, *, label: str, on_step: Optional[Callable] = None):
                                          seed=1))
         _overhead_probe(opt, step_j, fast_j, params, state, probe_batch,
                         args, lr_fn, log)
-    prof = ProfileCapture(args.profile_dir, steps=args.profile_steps)
+    # steps from a capture until its refresh is live
+    settle = (refresh_chunks + 1 if refresh_chunks > 1
+              else int(double_buffer))
+    prof = ProfileCapture(args.profile_dir,
+                          lambda t: any(ctrl.flags(t).values()),
+                          steps=args.profile_steps, settle=settle)
 
     import time as _time
     for t in range(1, args.steps + 1):
         batch = next(data)
         lr = lr_fn(t - 1)
         mom = 0.9 * lr / args.lr
-        flags = ctrl.flags(t)
         prof.step_start(t)
         t0 = _time.perf_counter()
-        if any(flags.values()):
-            jflags = {k: jnp.asarray(v) for k, v in flags.items()}
-            params, state, m = step_j(params, state, batch, jflags,
-                                      args.damping, lr, mom)
-            ctrl.update(t, flags, {k: (float(v[0]), float(v[1]))
-                                   for k, v in m["sims"].items()})
-        else:
-            params, state, m = fast_j(params, state, batch,
-                                      args.damping, lr, mom)
-            ctrl.update(t, flags, {})
+        params, state, m, flags = take_step(step_j, fast_j, ctrl, t, params,
+                                            state, batch, args.damping, lr,
+                                            mom)
         if on_step is not None or log.enabled:
             jax.block_until_ready(m["loss"])
             dt = _time.perf_counter() - t0
@@ -646,7 +704,7 @@ def run(cfg, args, *, label: str, on_step: Optional[Callable] = None):
                 evt["inverse"] = inverse_tally(m["inverse_info"],
                                                block_sizes)
             log.log_step(t, loss=float(m["loss"]), dt=dt, **evt)
-        prof.step_end(t)
+        prof.step_end(t, m)
         if t % 10 == 0 or t == 1:
             log.console(f"step {t:4d} loss {float(m['loss']):.4f} "
                         f"lr {lr:.4f} "

@@ -9,30 +9,40 @@ lives in ``experiments/make_report.py`` (overhead accounting) and
 from repro.obs.tracing import (
     STAGE_CAPTURE,
     STAGE_CHUNK,
+    STAGE_DRAIN,
+    STAGE_FLIP,
+    STAGE_FWD_BWD,
     STAGE_GATHER,
+    STAGE_HISTORY,
     STAGE_INVERSE,
     STAGE_PRECOND,
     STAGE_REDUCE,
+    STAGE_STATS,
+    STAGE_UPDATE,
     ProfileCapture,
     Span,
     SpanRecord,
     kernel_scope,
-    stage_scope,
 )
 from repro.obs.metrics import SCHEMA_VERSION, MetricsLogger, inverse_tally
 
 __all__ = [
     "STAGE_CAPTURE",
     "STAGE_CHUNK",
+    "STAGE_DRAIN",
+    "STAGE_FLIP",
+    "STAGE_FWD_BWD",
     "STAGE_GATHER",
+    "STAGE_HISTORY",
     "STAGE_INVERSE",
     "STAGE_PRECOND",
     "STAGE_REDUCE",
+    "STAGE_STATS",
+    "STAGE_UPDATE",
     "ProfileCapture",
     "Span",
     "SpanRecord",
     "kernel_scope",
-    "stage_scope",
     "SCHEMA_VERSION",
     "MetricsLogger",
     "inverse_tally",

@@ -114,7 +114,7 @@ def test_stage_and_kernel_scopes_trace():
     # named_scope is trace-time metadata only — must compose with jit
     @jax.jit
     def f(x):
-        with tracing.stage_scope(tracing.STAGE_INVERSE):
+        with jax.named_scope(tracing.STAGE_INVERSE):
             with tracing.kernel_scope("damped_inverse", "ref"):
                 return x * 2.0
     assert float(f(jnp.float32(3.0))) == 6.0
@@ -395,3 +395,62 @@ def test_inverse_info_off_by_default():
     _, _, m = jax.jit(opt.step)(params, state, batch, flags, 1e-3, 0.1, 0.0)
     assert "inverse_info" not in m
     assert {"loss", "sims", "grad_norm", "update_norm"} <= set(m)
+
+
+# ---------------------------------------------------------------------------
+# --profile-dir window
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("period,settle,steps,window", [
+    # chunked pipeline, K = 2: captures at 1, 4, 7, ...; the first refresh
+    # activates at step 4, which opens the window; 3 steps close it at 7
+    (3, 3, 3, (4, 6)),
+    # at least 4 steps: the window runs on to the next capture boundary
+    (3, 3, 4, (4, 9)),
+    # inline refresh every second step: the first fast step is step 2
+    (2, 0, 1, (3, 4)),
+    # a longer interval: the refresh is live before the second capture
+    (5, 3, 2, (6, 10)),
+])
+def test_profile_window_holds_whole_cycles(monkeypatch, period, settle,
+                                           steps, window):
+    calls = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda d: calls.append(("start", step)))
+    monkeypatch.setattr(jax.profiler, "stop_trace",
+                        lambda: calls.append(("stop", step)))
+    prof = tracing.ProfileCapture("dir", lambda t: t % period == 1 % period,
+                                  steps=steps, settle=settle)
+    for step in range(1, 16):
+        prof.step_start(step)
+        prof.step_end(step, {"loss": jnp.float32(step)})
+        if prof.done:
+            break
+    first, last = window
+    # opened before the first step of the window, closed before the
+    # capture step after its last
+    assert calls == [("start", first), ("stop", last + 1)]
+    prof.stop()
+    assert len(calls) == 2
+
+
+def test_profile_window_inert_and_stopped_early(monkeypatch):
+    calls = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda d: calls.append("start"))
+    monkeypatch.setattr(jax.profiler, "stop_trace",
+                        lambda: calls.append("stop"))
+    off = tracing.ProfileCapture(None, lambda t: True)
+    for t in range(1, 10):
+        off.step_start(t)
+        off.step_end(t)
+    assert off.done and calls == []
+    # a run that ends inside the window still closes it
+    prof = tracing.ProfileCapture("dir", lambda t: t % 3 == 1, steps=30,
+                                  settle=3)
+    for t in range(1, 8):
+        prof.step_start(t)
+        prof.step_end(t, {"loss": jnp.float32(t)})
+    assert calls == ["start"]
+    prof.stop()
+    assert calls == ["start", "stop"] and prof.done
