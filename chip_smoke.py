@@ -217,6 +217,9 @@ def phase_train(compiles: CompileLog) -> None:
         records.append(finite_record(rec))
 
     out = run(cfg, args, label=f"[train] {ARCH}", on_step=on_step)
+    for fam, (n, d) in out["precond_rows"].items():
+        say(f"[train] {fam}: preconditioned {n} of {d} rows "
+            f"({100 * n / d:.2f}%) in each step program")
     used = {r["program"] for r in records}
     n_fast = sum(r["program"] == "fast_step" for r in records)
     if records[0]["program"] != "train_step" or n_fast < MIN_FAST_STEPS:

@@ -32,7 +32,7 @@ from repro.core import kfac
 from repro.core.fisher import SiteInfo, emp_fisher_grads, mc_fisher_grads, get_path, set_path
 from repro.obs.tracing import (STAGE_CAPTURE, STAGE_DRAIN, STAGE_FWD_BWD,
                                STAGE_HISTORY, STAGE_INVERSE, STAGE_PRECOND,
-                               STAGE_STATS, STAGE_UPDATE)
+                               STAGE_PRECOND_ROWS, STAGE_STATS, STAGE_UPDATE)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -130,6 +130,14 @@ def _damped_inv(stat: jax.Array, kind: str, damp: jax.Array,
                                        return_info=return_info)  # bcast over blocks
     inv = 1.0 / (jnp.maximum(stat, 0.0) + damp[..., None])
     return (inv, None) if return_info else inv
+
+
+def _by_rows(info: SiteInfo, ids: Optional[jax.Array]) -> bool:
+    """Whether an embedding family's gradient is preconditioned at the rows
+    ``ids`` alone: its rows outside them are zero, ``A`` is diagonal and
+    ``G^-1`` acts from the right, so ``A^-1 dW G^-1`` is zero there too.
+    Worth it only with fewer ids than rows."""
+    return info.kind == "embed" and ids is not None and ids.size < info.d_in
 
 
 class SPNGD:
@@ -493,9 +501,30 @@ class SPNGD:
 
     # ---- preconditioned update for one family ----
 
-    def _apply_precond(self, fam: str, grads, curv: dict, lam):
+    def precond_rows(self, rows: Optional[dict]) -> dict[str, tuple]:
+        """``{family: (rows preconditioned, d_in)}`` of every embedding
+        family, for the ids ``rows`` (a model's ``site_rows``, or their
+        shapes) that a step program is given."""
+        rows = rows or {}
+        return {fam: ((rows[fam].size if _by_rows(info, rows.get(fam))
+                       else info.d_in), info.d_in)
+                for fam, info in self.infos.items() if info.kind == "embed"}
+
+    def _apply_precond(self, fam: str, grads, curv: dict, lam,
+                       ids: Optional[jax.Array] = None):
+        """``ids``: for an embedding family, the token ids of the step (any
+        shape), whose rows are the only ones its gradient can hold."""
         info = self.infos[fam]
         pc = curv["precond"]
+        if _by_rows(info, ids):
+            with jax.named_scope(STAGE_PRECOND_ROWS):
+                dw = get_path(grads, info.param)
+                ids = ids.reshape(-1)
+                a = pc.get("a")
+                u = kfac.precondition(dw[ids], None if a is None else a[ids],
+                                      pc.get("g"), backend=self.cfg.backend)
+                # a repeated id writes the same row again
+                return {info.param: jnp.zeros_like(dw).at[ids].set(u)}
         if info.kind in ("dense", "grouped", "embed"):
             dw = get_path(grads, info.param)
             u = kfac.precondition(dw, pc.get("a"), pc.get("g"),
@@ -538,13 +567,19 @@ class SPNGD:
 
     def _finish(self, params, state, grads, curv, lam, lr, mom, loss, aux,
                 sims, inverse_info: Optional[dict] = None,
-                extra_metrics: Optional[dict] = None):
+                extra_metrics: Optional[dict] = None,
+                rows: Optional[dict] = None):
+        """``rows``: ``{family: ids}`` from the model's ``site_rows`` of the
+        whole step's batch, or None, which preconditions every row (the
+        shard_map schedules: their gradients sum the rows of all shards)."""
         cfg = self.cfg
+        rows = rows or {}
         # preconditioned updates for sited params
         updates = {}
         with jax.named_scope(STAGE_PRECOND):
             for fam, c in curv.items():
-                updates.update(self._apply_precond(fam, grads, c, lam))
+                updates.update(self._apply_precond(fam, grads, c, lam,
+                                                   rows.get(fam)))
 
         # the first-order fallback, the norms, momentum and the parameter
         # update: disjoint from the preconditioning above
@@ -619,7 +654,7 @@ class SPNGD:
             return emp_fisher_grads(self.loss_fn, params, fstats, batch)
 
     def apply_update(self, params, state, grads, raw, counts, flags,
-                     lam, lr, mom, loss, aux):
+                     lam, lr, mom, loss, aux, rows: Optional[dict] = None):
         """Refresh curvature from raw sums (per ``flags``) + apply Eq. 23.
 
         With the chunked pipeline on (``refresh_chunks > 1``) this is the
@@ -627,7 +662,7 @@ class SPNGD:
         inversions are deferred to the next K fast steps' drains."""
         if self.pipeline is not None:
             return self._apply_capture(params, state, grads, raw, counts,
-                                       flags, lam, lr, mom, loss, aux)
+                                       flags, lam, lr, mom, loss, aux, rows)
         curv, sims, inv_info = {}, {}, {}
         for fam in raw:
             n_a, n_g = counts[fam]
@@ -637,10 +672,11 @@ class SPNGD:
             for key, v in fi.items():
                 inv_info[f"{fam}.{key}"] = v
         return self._finish(params, state, grads, curv, lam, lr, mom,
-                            loss, aux, sims, inverse_info=inv_info)
+                            loss, aux, sims, inverse_info=inv_info,
+                            rows=rows)
 
     def _apply_capture(self, params, state, grads, raw, counts, flags,
-                       lam, lr, mom, loss, aux):
+                       lam, lr, mom, loss, aux, rows=None):
         """Pipeline-mode refresh trigger: normalize + measure sims + shift
         history (so Algorithm 2 sees this step's similarities), park the
         normalized statistics in the raw store, and restart the drain
@@ -671,7 +707,7 @@ class SPNGD:
                 self.pipeline.chunks + 1, jnp.int32)}
         state = {**state, "pipeline": pipe}
         return self._finish(params, state, grads, curv, lam, lr, mom,
-                            loss, aux, sims, extra_metrics=extra)
+                            loss, aux, sims, extra_metrics=extra, rows=rows)
 
     def fast_curv(self, state, lam):
         """The fast path's curvature view + any pipeline progress: drains
@@ -688,16 +724,17 @@ class SPNGD:
                 {"refresh_inflight": inflight})
 
     def step(self, params, state, batch, flags: dict, lam, lr, mom,
-             rng: Optional[jax.Array] = None):
+             rng: Optional[jax.Array] = None, rows: Optional[dict] = None):
         """Full step with curvature capture. ``flags`` maps stat_name ->
-        bool (traced ok)."""
+        bool (traced ok); ``rows`` is the model's ``site_rows(batch)``."""
         loss, aux, grads, raw = self.grads_and_raw(params, batch, rng)
         with jax.named_scope(STAGE_STATS):
             counts = self.counts_fn(batch)
         return self.apply_update(params, state, grads, raw, counts, flags,
-                                 lam, lr, mom, loss, aux)
+                                 lam, lr, mom, loss, aux, rows)
 
-    def step_fast(self, params, state, batch, lam, lr, mom):
+    def step_fast(self, params, state, batch, lam, lr, mom,
+                  rows: Optional[dict] = None):
         """No capture, no refresh: backward + stale-preconditioned update
         (plus one pipeline drain chunk when ``refresh_chunks > 1``)."""
         with jax.named_scope(STAGE_FWD_BWD):
@@ -705,7 +742,7 @@ class SPNGD:
                 self.loss_fn, has_aux=True)(params, None, batch)
         state, curv, extra = self.fast_curv(state, lam)
         return self._finish(params, state, grads, curv, lam, lr, mom,
-                            loss, aux, {}, extra_metrics=extra)
+                            loss, aux, {}, extra_metrics=extra, rows=rows)
 
     # ---- double-buffer plumbing ----
 

@@ -10,6 +10,12 @@ sequentially; gradients average and raw factor sums add — the paper's own
 statistics-accumulation method for extreme batch sizes (§7.1). The G-type
 raw sums are rescaled by 1/accum^2 so the tokens-as-samples normalization
 stays exact (each microbatch's dL/ds carries a 1/n_micro, not 1/n_total).
+
+Both single-program builders hand the optimizer the model's
+``site_rows(batch)``: the token ids of the whole batch, microbatches
+included, so an embedding's gradient is preconditioned at those rows
+alone. The shard_map builders pass none: their gradient sums the rows of
+every data shard, which a shard's own ids do not cover.
 """
 
 from __future__ import annotations
@@ -51,6 +57,7 @@ def make_train_step(model, opt: SPNGD, accum: int = 1) -> Callable:
     def train_step(params, opt_state, batch, flags, lam, lr, mom):
         with jax.named_scope(STAGE_STATS):
             counts = model.site_counts(batch)      # full-batch counts
+        rows = model.site_rows(batch)
 
         if accum == 1:
             loss, aux, grads, raw = opt.grads_and_raw(params, batch)
@@ -84,7 +91,7 @@ def make_train_step(model, opt: SPNGD, accum: int = 1) -> Callable:
                 aux = {}
 
         return opt.apply_update(params, opt_state, grads, raw, counts,
-                                flags, lam, lr, mom, loss_mean, aux)
+                                flags, lam, lr, mom, loss_mean, aux, rows)
 
     return train_step
 
@@ -92,8 +99,10 @@ def make_train_step(model, opt: SPNGD, accum: int = 1) -> Callable:
 def make_fast_step(model, opt: SPNGD, accum: int = 1) -> Callable:
     """No-capture step (all statistics within their refresh interval)."""
     def fast_step(params, opt_state, batch, lam, lr, mom):
+        rows = model.site_rows(batch)
         if accum == 1:
-            return opt.step_fast(params, opt_state, batch, lam, lr, mom)
+            return opt.step_fast(params, opt_state, batch, lam, lr, mom,
+                                 rows)
         with jax.named_scope(STAGE_FWD_BWD):
             micro = jax.tree.map(
                 lambda x: x.reshape((accum, x.shape[0] // accum)
@@ -113,7 +122,7 @@ def make_fast_step(model, opt: SPNGD, accum: int = 1) -> Callable:
         opt_state, curv, extra = opt.fast_curv(opt_state, lam)
         return opt._finish(params, opt_state, grads, curv,
                            lam, lr, mom, loss_sum / accum, {}, {},
-                           extra_metrics=extra)
+                           extra_metrics=extra, rows=rows)
 
     return fast_step
 
@@ -546,8 +555,10 @@ def run(cfg, args, *, label: str, on_step: Optional[Callable] = None):
     ``{"step", "program", "loss", "grad_norm", "update_norm", "dt"}``
     (``program`` is ``"train_step"`` or ``"fast_step"``; ``dt`` waits for
     the step's loss). Returns ``{"params", "state", "opt", "programs",
-    "batch"}``: the final parameters and optimizer state, the optimizer,
-    the two jitted step programs, and the last batch."""
+    "batch", "precond_rows"}``: the final parameters and optimizer state,
+    the optimizer, the two jitted step programs, the last batch, and
+    ``{family: (rows, d_in)}`` of each embedding family's preconditioning
+    (:meth:`~repro.core.ngd.SPNGD.precond_rows`)."""
     import dataclasses
 
     from repro import comm as comm_lib
@@ -625,6 +636,14 @@ def run(cfg, args, *, label: str, on_step: Optional[Callable] = None):
              inverse_sharding=inverse_sharding,
              double_buffer=double_buffer,
              refresh_chunks=refresh_chunks)
+    # the rows each embedding family's preconditioning covers: fixed by
+    # the batch's shape, so one report per compiled program
+    precond_rows = opt.precond_rows(model.site_rows(
+        {"tokens": jax.ShapeDtypeStruct((args.batch, args.seq), jnp.int32)}))
+    for program in ("train_step", "fast_step"):
+        log.emit("precond_rows", program=program,
+                 families={fam: {"rows": n, "d_in": d, "share": n / d}
+                           for fam, (n, d) in precond_rows.items()})
     # per-block-size Stage-4 tallies need each stat's block size, which the
     # on-device info arrays don't carry — read it off the stats template
     block_sizes = {}
@@ -723,7 +742,8 @@ def run(cfg, args, *, label: str, on_step: Optional[Callable] = None):
     log.emit("summary", **ctrl.summary_flat())
     log.close()
     return {"params": params, "state": state, "opt": opt, "batch": batch,
-            "programs": {"train_step": step_j, "fast_step": fast_j}}
+            "programs": {"train_step": step_j, "fast_step": fast_j},
+            "precond_rows": precond_rows}
 
 
 def main():

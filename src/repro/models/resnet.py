@@ -184,3 +184,7 @@ class ConvNet:
                 counts[f"{nm}_wskip"] = (b * h * w_, b)
         counts["head"] = (b, b)
         return {k: v for k, v in counts.items() if k in self.fstats()}
+
+    def site_rows(self, batch) -> dict:
+        """No embedding family: every gradient row is preconditioned."""
+        return {}

@@ -12,7 +12,8 @@ Model surface used by the rest of the framework:
   loss(params, fstats, batch) -> (loss, aux)        # train step objective
   forward(params, batch, fstats) -> (logits, aux)   # prefill
   init_cache(batch, max_len) / decode_step(params, cache, tokens)
-  site_infos() / fstats() / site_counts(batch)      # SP-NGD wiring
+  site_infos() / fstats()                           # SP-NGD wiring
+  site_counts(batch) / site_rows(batch)
   input_specs(shape) -> ShapeDtypeStruct batch      # dry-run stand-ins
 """
 
@@ -743,6 +744,11 @@ class DecoderLM:
             else:
                 counts[fam] = (n_total, n_loss)
         return counts
+
+    def site_rows(self, batch) -> dict:
+        """``{family: ids}``: the rows of each embedding family's gradient
+        that a step on ``batch`` can make nonzero."""
+        return {"embed": batch["tokens"]}
 
     # ------------------------------------------------------------------
     # dry-run input stand-ins

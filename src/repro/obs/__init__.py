@@ -16,6 +16,7 @@ from repro.obs.tracing import (
     STAGE_HISTORY,
     STAGE_INVERSE,
     STAGE_PRECOND,
+    STAGE_PRECOND_ROWS,
     STAGE_REDUCE,
     STAGE_STATS,
     STAGE_UPDATE,
@@ -36,6 +37,7 @@ __all__ = [
     "STAGE_HISTORY",
     "STAGE_INVERSE",
     "STAGE_PRECOND",
+    "STAGE_PRECOND_ROWS",
     "STAGE_REDUCE",
     "STAGE_STATS",
     "STAGE_UPDATE",

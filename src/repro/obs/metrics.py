@@ -7,6 +7,9 @@ One :class:`MetricsLogger` owns all run-time telemetry output:
   unix time}``. Event types emitted by the launchers:
 
   - ``run_config``   — once at start: arch, flags, param count
+  - ``precond_rows`` — once per step program, at start: for each
+                       embedding family the rows its preconditioning
+                       covers, ``d_in``, and their ``share``
   - ``step``         — per training step: loss, lr, refresh decisions,
                        grad/update norms, step-time EMA + p50/p99 from a
                        rolling window, the IntervalController's drained

@@ -45,6 +45,9 @@ STAGE_REDUCE = "spngd.stage3.reduce"       # factor ReduceScatterV
 STAGE_INVERSE = "spngd.stage4.inverse"     # damped factor inversion
 STAGE_GATHER = "spngd.stage4.gather"       # preconditioner all-gather
 STAGE_PRECOND = "spngd.stage4.precond"     # A^-1 dW G^-1 apply
+# An embedding's A^-1 dW G^-1 at the rows its step touched, nested in
+# STAGE_PRECOND (gather, the row product, scatter into zeros).
+STAGE_PRECOND_ROWS = "spngd.stage4.precond.rows"
 STAGE_UPDATE = "spngd.update"              # fallback, norms, momentum, step
 # Chunked refresh pipeline (repro.core.pipeline): one drain chunk fused
 # into a fast step. STAGE_INVERSE / STAGE_GATHER nest under it, so trace

@@ -55,6 +55,8 @@ def test_run_reports_each_step(chunks, programs):
         assert all(np.isfinite(r[k])
                    for k in ("loss", "grad_norm", "update_norm", "dt"))
     assert set(out["programs"]) == {"train_step", "fast_step"}
+    # the embedding is preconditioned at the batch's 2 x 16 token rows
+    assert out["precond_rows"] == {"embed": (32, cfg.vocab)}
     # the returned state is live (donation consumed only the old buffers)
     assert all(not x.is_deleted() for x in jax.tree.leaves(out["state"]))
 
@@ -69,3 +71,8 @@ def test_run_overhead_probe_survives_donation(tmp_path):
     probe, = [e for e in events if e["type"] == "probe"]
     assert probe["fast_us"] > 0 and probe["refresh_us"] > 0
     assert sum(e["type"] == "step" for e in events) == 2
+    rows = {e["program"]: e["families"] for e in events
+            if e["type"] == "precond_rows"}
+    assert rows == {p: {"embed": {"rows": 32, "d_in": cfg.vocab,
+                                  "share": 32 / cfg.vocab}}
+                    for p in ("train_step", "fast_step")}
