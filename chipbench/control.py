@@ -1,14 +1,16 @@
 """Readings that set a cell's correctness limits, in one process:
 
     python3 chipbench/control.py --workload NAME --seeds 1,2,... \\
-        [--control-seeds 7,8,9] [--fault half_batch] [--out FILE]
+        [--control-seeds 7,8,9] [--fault half_batch] [--fault drop_shard] \\
+        [--out FILE]
 
 For every ``--seeds`` seed, the program's checked steps (the same call and
 feed as a run of ``run.py``) against the plain reference: the lower
 readings. For every ``--control-seeds`` seed, the reference computed with
 the configuration's control operand dtype put in the program's place: the
 upper readings; and, with ``--fault``, the reference with that fault
-planted. Each reading is one JSON line (also written to ``--out``). The
+planted. A control seed that is also a ``--seeds`` seed reuses its plain
+reference. Each reading is one JSON line (also written to ``--out``). The
 benchmark's own runs never run this.
 """
 
@@ -53,19 +55,20 @@ def main(argv=None) -> int:
             out.write(line + "\n")
             out.flush()
 
+        refs = {}
         for seed in [int(s) for s in args.seeds.split(",") if s]:
             t0 = time.perf_counter()
             program, live = sess.start(seed)
             del live
             gc.collect()
-            ref = sess.reference(seed)
+            ref = refs[seed] = sess.reference(seed)
             emit("program", seed, harness.compare(program, ref),
                  {"seconds": time.perf_counter() - t0,
                   "left_out": harness.left_out(ref),
                   "losses": program["loss"], "ref_losses": ref["loss"]})
         op = cell.config["precision"]["control_operands"]
         for seed in [int(s) for s in args.control_seeds.split(",") if s]:
-            ref = sess.reference(seed)
+            ref = refs.get(seed) or sess.reference(seed)
             ctl = sess.reference(seed, op_dtype=op)
             emit("control", seed, harness.compare(ctl, ref), {"op_dtype": op})
             for fault in args.fault:

@@ -25,6 +25,7 @@ refresh cycle, and times whole refresh cycles for at least ``--seconds``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import gc
 import importlib
 import importlib.util
@@ -142,10 +143,11 @@ def leaf_paths(tree) -> list[str]:
             for path, _ in flat]
 
 
-def make_init(template, leaf_init: Callable) -> Callable:
+def make_init(template, leaf_init: Callable, out_shardings=None) -> Callable:
     """``init(key_data) -> params`` shaped like ``template``: every leaf
     drawn on the device from its own fold of the seed's key, in the leaf's
-    dtype. One jitted call makes them all."""
+    dtype. One jitted call makes them all, on ``out_shardings`` where
+    given."""
     import jax
     import jax.numpy as jnp
     paths = leaf_paths(template)
@@ -165,10 +167,10 @@ def make_init(template, leaf_init: Callable) -> Callable:
                for i, (p, s) in enumerate(zip(paths, leaves))]
         return jax.tree.unflatten(treedef, out)
 
-    return jax.jit(init)
+    return jax.jit(init, out_shardings=out_shardings)
 
 
-def make_batches(cell: Cell, n: int) -> Callable:
+def make_batches(cell: Cell, n: int, out_shardings=None) -> Callable:
     import jax
 
     def batches(key_data):
@@ -176,7 +178,7 @@ def make_batches(cell: Cell, n: int) -> Callable:
         return cell.family.make_batches(jax.random.fold_in(key, 1 << 30),
                                         cell.traffic, cell.config, n)
 
-    return jax.jit(batches)
+    return jax.jit(batches, out_shardings=out_shardings)
 
 
 def leaf_norms(tree):
@@ -210,11 +212,15 @@ class Program:
     opt: Any
     step: Callable          # jitted make_train_step, donates params + state
     fast: Callable          # jitted make_fast_step, donates params + state
-    init: Callable          # weights from the seed (jitted)
-    init_state: Callable    # the optimizer's own init (jitted)
+    init: Callable          # weights from the seed (jitted), on the chips
+    init_state: Callable    # the optimizer's own init (jitted), on the chips
     damping: float
     lr: float
     mom: float
+    # a cell on several chips: its batches' sharding, and the weights drawn
+    # on one chip for the reference
+    batch_sharding: Any = None
+    ref_init: Optional[Callable] = None
 
     def controller(self, interval: int):
         """Algorithm 2's controller with its interval pinned."""
@@ -227,8 +233,11 @@ class Program:
 
 def build_program(cell: Cell, wrap_step: Optional[Callable] = None
                   ) -> Program:
-    """``wrap_step(kind, fn)``, for tests only, plants a fault under the
-    timed path before it is jitted."""
+    """One chip: ``make_train_step`` / ``make_fast_step`` jitted as they
+    are. Several chips: the data-parallel schedule, ``make_shardmap_train_step``
+    / ``make_shardmap_fast_step`` on a ``(data=chips, model=1)`` mesh
+    (:func:`_mesh_steps`). ``wrap_step(kind, fn)``, for tests only, plants
+    a fault under the timed path before it is jitted."""
     import jax
     import jax.numpy as jnp
 
@@ -243,21 +252,85 @@ def build_program(cell: Cell, wrap_step: Optional[Callable] = None
                           inverse_method=o["inverse_method"],
                           factor_dtype=jnp.dtype(o["factor_dtype"]),
                           double_buffer=True,
-                          refresh_chunks=o["refresh_chunks"]))
+                          refresh_chunks=o["refresh_chunks"],
+                          inverse_sharding=cell.traffic.get("stage4")
+                          == "sharded"))
     accum = cell.traffic["accum"]
+    template = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    if cell.chips > 1:
+        return _mesh_steps(cell, model, opt, template, wrap_step)
     step = make_train_step(model, opt, accum=accum)
     fast = make_fast_step(model, opt, accum=accum)
     if wrap_step is not None:
         step, fast = wrap_step("train", step), wrap_step("fast", fast)
-    template = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    init = make_init(template, cell.family.leaf_init)
     return Program(
         opt=opt,
         step=jax.jit(step, donate_argnums=(0, 1)),
         fast=jax.jit(fast, donate_argnums=(0, 1)),
-        init=make_init(template, cell.family.leaf_init),
+        init=init,
         init_state=jax.jit(opt.init),
         damping=float(o["damping"]), lr=float(o["lr"]),
-        mom=float(o["momentum"]))
+        mom=float(o["momentum"]), ref_init=init)
+
+
+def _mesh_steps(cell: Cell, model, opt, template,
+                wrap_step: Optional[Callable]) -> Program:
+    """The data-parallel schedule on ``cell.chips`` chips, wired as
+    ``chip_smoke.py --four-chips`` wires it: Stage 1-2 on each chip's shard
+    of the batch inside a shard_map manual over every mesh axis (so the
+    Pallas kernels resolve as on one chip), Stage 3 by the default
+    ``FactorReducer`` (gradient psum, dense psum_scatter of the factors),
+    Stage 4 sharded by owner where the traffic says ``"stage4":
+    "sharded"``. Weights and velocity are replicated, the factor state
+    lies along its leading axis as the reducer scatters it
+    (``launch/sharding.opt_state_pspecs``), each batch is split over
+    ``data``; the steps' outputs are pinned to that layout, so every call
+    after the first hits the same program."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.launch.mesh import make_mesh
+    from repro.launch.sharding import opt_state_pspecs
+    from repro.launch.train import (make_shardmap_fast_step,
+                                    make_shardmap_train_step)
+
+    o, traffic = cell.config["optimizer"], cell.traffic
+    if traffic.get("mesh", {}).get("data") != cell.chips:
+        raise SystemExit(f"{cell.name}: traffic mesh {traffic.get('mesh')} "
+                         f"does not split the batch over its {cell.chips} "
+                         "chips")
+    mesh = make_mesh((cell.chips, 1), ("data", "model"))
+    accum = traffic["accum"]
+    step = make_shardmap_train_step(model, opt, mesh, accum=accum,
+                                    manual_axes="all")
+    fast = make_shardmap_fast_step(model, opt, mesh, accum=accum,
+                                   manual_axes="all")
+    if wrap_step is not None:
+        step, fast = wrap_step("train", step), wrap_step("fast", fast)
+    repl = NamedSharding(mesh, P())
+    specs = opt_state_pspecs(jax.eval_shape(opt.init, template),
+                             jax.tree.map(lambda _: P(), template), mesh)
+    state_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                            is_leaf=lambda x: isinstance(x, P))
+
+    def on_mesh(fn):
+        @functools.wraps(fn)
+        def traced(*args):
+            # the kernels' dispatch reads the mesh while tracing
+            with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+                return fn(*args)
+        return jax.jit(traced, donate_argnums=(0, 1),
+                       out_shardings=(repl, state_sh, repl))
+
+    return Program(
+        opt=opt, step=on_mesh(step), fast=on_mesh(fast),
+        init=make_init(template, cell.family.leaf_init, repl),
+        init_state=jax.jit(opt.init, out_shardings=state_sh),
+        damping=float(o["damping"]), lr=float(o["lr"]),
+        mom=float(o["momentum"]),
+        batch_sharding=NamedSharding(mesh, P("data")),
+        ref_init=make_init(template, cell.family.leaf_init))
 
 
 def train_step(prog: Program, ctrl, t: int, params, state, batch,
@@ -365,9 +438,18 @@ class Session:
             raise SystemExit(f"the pool of {traffic['pool']} batches is "
                              f"smaller than the {self.n_check} checked steps")
         self.prog = build_program(cell, wrap_step)
-        self.make_pool = make_batches(cell, traffic["pool"])
+        self.make_pool = make_batches(cell, traffic["pool"],
+                                      self.prog.batch_sharding)
         self.change_norms = make_change_norms(self.prog.init)
         self.norms = jax.jit(leaf_norms)
+        # the reference runs on one chip, from weights and batches drawn
+        # there; on one chip they are the program's own
+        if self.prog.ref_init is self.prog.init:
+            self.ref_pool, self.ref_change = self.make_pool, \
+                self.change_norms
+        else:
+            self.ref_pool = make_batches(cell, traffic["pool"])
+            self.ref_change = make_change_norms(self.prog.ref_init)
 
     def start(self, seed: int, annotate: bool = False):
         """Weights, optimizer state and batches from the seed; the checked
@@ -408,12 +490,12 @@ class Session:
         import numpy as np
         key_data = seed_key_data(seed)
         ref = self.cell.reference.run(
-            self.cell.config, self.cell.traffic, self.prog.init(key_data),
-            self.make_pool(key_data)[:self.n_check], self.n_check,
+            self.cell.config, self.cell.traffic, self.prog.ref_init(key_data),
+            self.ref_pool(key_data)[:self.n_check], self.n_check,
             op_dtype=op_dtype, fault=fault)
         params = ref.pop("params")
         ref["change"] = dict(zip(leaf_paths(params), np.asarray(
-            self.change_norms(params, key_data)).tolist()))
+            self.ref_change(params, key_data)).tolist()))
         return ref
 
 
@@ -437,15 +519,19 @@ def device_info(cell: Cell, device_check: bool):
     return dev, table[dev["kind"]]
 
 
-def device_peak_bytes() -> int:
-    """The chip's peak memory so far: ``peak_bytes_in_use`` (buffers)
-    plus ``peak_bytes_reserved``, the region where the TPU runtime holds
-    a program's temporaries, which ``peak_bytes_in_use`` leaves out."""
+def device_peak_bytes(chips: int) -> int:
+    """The peak memory so far of the fullest of the first ``chips`` chips:
+    ``peak_bytes_in_use`` (buffers) plus ``peak_bytes_reserved``, the
+    region where the TPU runtime holds a program's temporaries, which
+    ``peak_bytes_in_use`` leaves out."""
     import jax
-    stats = jax.devices()[0].memory_stats()
-    say(f"memory_stats: {stats}")
-    return int(stats["peak_bytes_in_use"]) + \
-        int(stats.get("peak_bytes_reserved", 0))
+    peaks = []
+    for dev in jax.devices()[:chips]:
+        stats = dev.memory_stats()
+        say(f"memory_stats {dev.id}: {stats}")
+        peaks.append(int(stats["peak_bytes_in_use"])
+                     + int(stats.get("peak_bytes_reserved", 0)))
+    return max(peaks)
 
 
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
@@ -494,7 +580,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
                          "inside the timed window")
     window_losses = [float(x) for x in window_losses]
     failed = sum(not math.isfinite(x) for x in window_losses)
-    memory_peak = device_peak_bytes() if device_check else 0
+    memory_peak = device_peak_bytes(cell.chips) if device_check else 0
     say(f"[{cell.name}] window: {steps} steps ({steps // interval} refresh "
         f"cycles) in {window_s:.4f} s; losses first {window_losses[0]!r} "
         f"max {max(window_losses)!r} last {window_losses[-1]!r}; "
@@ -502,7 +588,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
 
     ctx = types.SimpleNamespace(
         cell=cell, family=cell.family, config=cell.config,
-        traffic=cell.traffic, peaks=peaks,
+        traffic=cell.traffic, peaks=peaks, chips=cell.chips,
         setup_s=setup_s, window_s=window_s, steps=steps,
         items=steps * cell.family.items_per_step(cell.traffic),
         item=cell.family.ITEM, captures=steps // interval,
@@ -511,6 +597,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     if trace:
         from chipbench import trace as tr
         ctx.trace = tr.reduce_dir(trace_dir)
+        say(f"[{cell.name}] trace: busy {ctx.trace.busy_s!r} s of "
+            f"{ctx.trace.window_s!r} s; collectives "
+            f"{ctx.trace.collective_s()!r} s, exposed "
+            f"{ctx.trace.exposed_collective_s()!r} s (a chip's mean)")
         dev["busy_s"] = ctx.trace.busy_s
         dev["window_s"] = ctx.trace.window_s
         breakdown = ctx.trace.breakdown()

@@ -10,9 +10,11 @@ from chipbench import work
 
 
 def mfu(ctx):
+    """The model's FLOPs over the window, over the peak of all its chips
+    (``ctx.items`` counts every chip's items)."""
     f = ctx.family.model_flops_per_item(ctx.config, ctx.traffic)
     return 100.0 * f * ctx.items / ctx.window_s / \
-        ctx.peaks["bf16_flops_per_s"]
+        (ctx.chips * ctx.peaks["bf16_flops_per_s"])
 
 
 def busy_share(ctx, *scopes):
@@ -28,8 +30,14 @@ def idle_share(ctx):
 
 
 def precond_work(ctx) -> work.Work:
+    """One chip's preconditioning work a step. On several chips the
+    preconditioner state lies split along its leading axis (the layers;
+    the vocabulary of the embedding's and the head's diagonal factors), and
+    the compiler splits the products with it: a chip does 1/chips of them
+    (per-device shapes of the step programs compiled for a v5e 2x2)."""
     return work.precond_work(ctx.family.dense_sites(ctx.config),
-                             ctx.config["optimizer"]["kfac_max_dim"])
+                             ctx.config["optimizer"]["kfac_max_dim"]) * \
+        (1.0 / ctx.chips)
 
 
 def kernel_roofline(ctx, w: work.Work, *scopes):

@@ -28,8 +28,12 @@ dtype instead.
 
 Gradients are formed from each site's input and output cotangent (dW =
 sum_t a_t g_t^T), so the weights are never differentiated in float32 as a
-whole: that keeps a 4-layer stage at published widths inside one chip's
-memory once the program's state is freed.
+whole; and a step's batch is taken :data:`ROW_BLOCK` sequences at a time,
+the blocks' gradients and factor sums added up before the update. The
+mean is over the whole batch: that is the data-parallel step's
+mathematics (the mean over every chip's sequences, factors over all of
+them), computed on one chip. Both keep a 4-layer stage at published
+widths inside one chip's memory once the program's state is freed.
 """
 
 from __future__ import annotations
@@ -61,6 +65,8 @@ BLOCK_NORMS = [("ln1", "ln1/gamma", "xh1"), ("ln2", "ln2/gamma", "xh2")]
 # the head's logits are formed this many chunks of tokens per sequence at
 # a time
 HEAD_CHUNKS = 2
+# sequences whose forward and backward run at a time
+ROW_BLOCK = 2
 
 
 def _rms(x):
@@ -129,12 +135,12 @@ def _head_chunks(hf, labels, n_chunks: int):
             labels.reshape(n_chunks, n // n_chunks))
 
 
-def _head(hf, labels, w, op, n_chunks: int):
-    """Mean cross-entropy of the head's logits, taken over tokens in
-    chunks so a whole-vocabulary logit matrix never exists at once.
-    Returns the loss, dL/dhf, and each logit's squared cotangent summed
-    over tokens (the head's diagonal G before normalization)."""
-    n = labels.size
+def _head(hf, labels, w, op, n_chunks: int, n: int):
+    """Cross-entropy of the head's logits summed over these tokens and
+    divided by the batch's ``n``, taken over tokens in chunks so a
+    whole-vocabulary logit matrix never exists at once. Returns the loss,
+    dL/dhf, and each logit's squared cotangent summed over tokens (the
+    head's diagonal G before normalization)."""
     hc, yc = _head_chunks(hf, labels, n_chunks)
 
     def body(carry, xs):
@@ -154,9 +160,8 @@ def _head(hf, labels, w, op, n_chunks: int):
     return nll / n, d_h.reshape(hf.shape), gsq
 
 
-def _head_grad(hf, labels, w, op, n_chunks: int):
+def _head_grad(hf, labels, w, op, n_chunks: int, n: int):
     """dL/dW of the head, summed over the same token chunks."""
-    n = labels.size
     hc, yc = _head_chunks(hf, labels, n_chunks)
 
     def body(acc, xs):
@@ -182,8 +187,9 @@ def _zeros_eps(cfg, b, s):
     return {"embed": z(b, s, d), "blocks": blk, "final": z(b, s, d)}
 
 
-def _backward(params, batch, cfg, op):
-    """Loss, every site's input (f32) and output cotangent, and the head's
+def _backward(params, batch, n, cfg, op):
+    """This block's share of the loss (its tokens' sum over the batch's
+    ``n``), every site's input (f32) and output cotangent, and the head's
     squared logit cotangents."""
     b, s = batch["tokens"].shape
     eps = _zeros_eps(cfg, b, s)
@@ -192,7 +198,8 @@ def _backward(params, batch, cfg, op):
         has_aux=True)
     loss, d_hf, head_gsq = _head(hf.reshape(b * s, -1),
                                  batch["labels"].reshape(-1),
-                                 params["head"]["w"], op, HEAD_CHUNKS * b)
+                                 params["head"]["w"], op, HEAD_CHUNKS * b,
+                                 n)
     (gy,) = vjp(d_hf.reshape(hf.shape))
     return loss, acts, xhf, hf, gy, head_gsq
 
@@ -201,13 +208,12 @@ def _flat(x):
     return x.reshape(-1, x.shape[-1])
 
 
-def _statistics(batch, acts, xhf, hf, gy, head_gsq, cfg, op):
-    """Normalized factors of every site (Eq. 10-13, 15): A over the input
-    side, G over the output side scaled by n (the per-sample gradient is n
-    times the mean loss's)."""
+def _statistics(batch, acts, xhf, hf, gy, head_gsq, n, cfg, op):
+    """This block's share of every site's normalized factors (Eq. 10-13,
+    15): A over the input side, G over the output side scaled by the
+    batch's n (the per-sample gradient is n times the mean loss's)."""
     md, v = cfg["optimizer"]["kfac_max_dim"], cfg["vocab_size"]
     tok = batch["tokens"]
-    n = tok.size
     per_layer = jax.vmap(lambda x: gram(_flat(x), md, op))
     st = {"embed": {"a": jnp.zeros((v,), F32).at[tok.reshape(-1)].add(1.0)
                     / n,
@@ -291,11 +297,12 @@ def _norm(x):
     return jnp.sqrt(jnp.sum(jnp.square(x)))
 
 
-def _update_head(w, v, hf, labels, pc, cfg, op):
+def _update_head(w, v, hf, labels, pc, n, cfg, op):
     """The head's gradient (summed over token chunks), A^-1 dW G^-1 with a
     diagonal G, and its heavy-ball step."""
     o, b = cfg["optimizer"], labels.shape[0]
-    dw = _head_grad(_flat(hf), labels.reshape(-1), w, op, HEAD_CHUNKS * b)
+    dw = _head_grad(_flat(hf), labels.reshape(-1), w, op, HEAD_CHUNKS * b,
+                    n)
     u = right(left(pc["a"], dw, True, o["kfac_max_dim"], op), pc["g"],
               False, o["kfac_max_dim"], op)
     w, v = momentum(w, v, u, o["lr"], o["momentum"])
@@ -313,37 +320,41 @@ def _update_embed(table, v, tokens, gy, pc, cfg, op):
     return table, v, _norm(dw)
 
 
-def _update_rest(params, vel, acts, xhf, gy, pc, cfg, op):
-    """The blocks and the final norm: gradients from sites (dW = sum_t
-    a_t g_t^T), preconditioned, heavy-ball step; ``params`` and ``vel``
-    hold the "blocks" and "final_norm" subtrees."""
+def _rest_grads(acts, xhf, gy, op):
+    """This block's share of the gradients of the blocks and the final
+    norm, from sites (dW = sum_t a_t g_t^T)."""
+    blk = gy["blocks"]
+    grads = {"final_norm/gamma": jnp.sum(_flat(gy["final"] * xhf), 0)}
+    layer_dw = jax.vmap(lambda x, g: dot("nd,ne->de", _flat(x), _flat(g),
+                                         op))
+    for _, path, bias, a_name, g_name in BLOCK_DENSE:
+        grads[f"blocks/{path}"] = layer_dw(acts[a_name], blk[g_name])
+        if bias:
+            grads[f"blocks/{bias}"] = jnp.sum(blk[g_name], (1, 2))
+    for site, path, xh in BLOCK_NORMS:
+        grads[f"blocks/{path}"] = jnp.sum(blk[site] * acts[xh], (1, 2))
+    return grads
+
+
+def _update_rest(params, vel, grads, pc, cfg, op):
+    """The blocks and the final norm: their gradients preconditioned, the
+    heavy-ball step; ``params`` and ``vel`` hold the "blocks" and
+    "final_norm" subtrees."""
     o = cfg["optimizer"]
     md, lam, lr, mom = (o["kfac_max_dim"], o["damping"], o["lr"],
                         o["momentum"])
-    blk = gy["blocks"]
-    grads, ups = {}, {}
-
-    def nat(site, dw):
-        return right(left(pc[site]["a"], dw, True, md, op), pc[site]["g"],
-                     True, md, op)
-
-    gfin = jnp.sum(_flat(gy["final"] * xhf), 0)
-    grads["final_norm/gamma"] = gfin
-    ups["final_norm/gamma"] = gfin / (pc["final_norm"]["uw"] + lam)
-    layer_dw = jax.vmap(lambda x, g: dot("nd,ne->de", _flat(x), _flat(g),
-                                         op))
-    for site, path, bias, a_name, g_name in BLOCK_DENSE:
-        dw = layer_dw(acts[a_name], blk[g_name])
-        grads[f"blocks/{path}"] = dw
-        ups[f"blocks/{path}"] = nat(site, dw)
+    ups = {"final_norm/gamma": grads["final_norm/gamma"]
+           / (pc["final_norm"]["uw"] + lam)}
+    for site, path, bias, _, _ in BLOCK_DENSE:
+        ups[f"blocks/{path}"] = right(
+            left(pc[site]["a"], grads[f"blocks/{path}"], True, md, op),
+            pc[site]["g"], True, md, op)
         if bias:
-            db = jnp.sum(blk[g_name], (1, 2))
-            grads[f"blocks/{bias}"] = db
-            ups[f"blocks/{bias}"] = db / (pc[site]["d"] + lam)
-    for site, path, xh in BLOCK_NORMS:
-        dg = jnp.sum(blk[site] * acts[xh], (1, 2))
-        grads[f"blocks/{path}"] = dg
-        ups[f"blocks/{path}"] = dg / (pc[site]["uw"] + lam)
+            ups[f"blocks/{bias}"] = grads[f"blocks/{bias}"] / \
+                (pc[site]["d"] + lam)
+    for site, path, _ in BLOCK_NORMS:
+        ups[f"blocks/{path}"] = grads[f"blocks/{path}"] / \
+            (pc[site]["uw"] + lam)
     new_p, new_v = {}, {}
     for path, u in ups.items():
         new_p[path], new_v[path] = momentum(get(params, path),
@@ -352,21 +363,42 @@ def _update_rest(params, vel, acts, xhf, gy, pc, cfg, op):
             {k: _norm(g) for k, g in grads.items()})
 
 
+_tree_add = jax.jit(lambda a, b: jax.tree.map(jnp.add, a, b),
+                    donate_argnums=0)
+
+
+def _add(acc, x):
+    """``acc + x`` leaf by leaf; the first block's share is taken as is."""
+    return x if acc is None else _tree_add(acc, x)
+
+
 @functools.lru_cache(maxsize=4)
 def _programs(config_json: str, op):
-    """The reference's jitted programs for one configuration."""
+    """The reference's jitted programs for one configuration; ``n``, the
+    batch's token count, is static."""
     cfg = json.loads(config_json)
     return types.SimpleNamespace(
-        backward=jax.jit(lambda p, b: _backward(p, b, cfg, op)),
-        statistics=jax.jit(lambda b, a, x, h, g, s: _statistics(
-            b, a, x, h, g, s, cfg, op)),
+        backward=jax.jit(lambda p, b, n: _backward(p, b, n, cfg, op),
+                         static_argnums=2),
+        statistics=jax.jit(lambda b, a, x, h, g, s, n: _statistics(
+            b, a, x, h, g, s, n, cfg, op), static_argnums=6),
+        rest_grads=jax.jit(lambda a, x, g: _rest_grads(a, x, g, op)),
         inverses=jax.jit(lambda st: _inverses(st, cfg)),
-        head=jax.jit(lambda w, v, h, y, pc: _update_head(
-            w, v, h, y, pc, cfg, op), donate_argnums=(0, 1)),
+        head=jax.jit(lambda w, v, h, y, pc, n: _update_head(
+            w, v, h, y, pc, n, cfg, op), donate_argnums=(0, 1),
+            static_argnums=5),
         embed=jax.jit(lambda w, v, t, g, pc: _update_embed(
             w, v, t, g, pc, cfg, op), donate_argnums=(0, 1)),
-        rest=jax.jit(lambda p, v, a, x, g, pc: _update_rest(
-            p, v, a, x, g, pc, cfg, op), donate_argnums=(0, 1)))
+        rest=jax.jit(lambda p, v, g, pc: _update_rest(
+            p, v, g, pc, cfg, op), donate_argnums=(0, 1)))
+
+
+def _rows(batch, start: int, stop: int) -> dict:
+    return jax.tree.map(lambda x: x[start:stop], batch)
+
+
+def _joined(parts: list):
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
 
 def run(config: dict, traffic: dict, params, batches: list, steps: int,
@@ -375,7 +407,9 @@ def run(config: dict, traffic: dict, params, batches: list, steps: int,
     Returns each step's loss, the first step's gradient norms as the
     optimizer took them (velocity / lr) and before preconditioning, and
     the final parameters. ``fault`` plants a known error: ``"half_batch"``
-    trains on the first half of each batch's rows."""
+    trains on the first half of each batch's rows (the mean over them);
+    ``"drop_shard"`` leaves the rows of the traffic's last data shard out
+    of the gradient and factor sums (the mean still over every row)."""
     if traffic["accum"] != 1:
         raise NotImplementedError("the LM reference takes accum = 1")
     op = None if op_dtype is None else jnp.dtype(op_dtype).name
@@ -389,29 +423,47 @@ def run(config: dict, traffic: dict, params, batches: list, steps: int,
     for t, (capture, activates) in enumerate(
             schedule(traffic["interval"], o["refresh_chunks"], steps), 1):
         batch = batches[t - 1]
+        rows = batch["tokens"].shape[0]
         if fault == "half_batch":
-            half = batch["tokens"].shape[0] // 2
-            batch = jax.tree.map(lambda x: x[:half], batch)
+            rows //= 2
+            batch = _rows(batch, 0, rows)
+        n = batch["tokens"].size
+        if fault == "drop_shard":
+            rows -= rows // traffic["mesh"]["data"]
         if activates is not None:
             pc = f.inverses(pending.pop(activates))
-        loss, acts, xhf, hf, gy, head_gsq = f.backward(params, batch)
+        loss, stats, grads, hf, gy_embed, blocks = 0.0, None, None, [], [], []
+        for i in range(0, rows, ROW_BLOCK):
+            block = _rows(batch, i, min(i + ROW_BLOCK, rows))
+            part, acts, xhf, h, gy, head_gsq = f.backward(params, block, n)
+            loss = loss + part
+            if capture:
+                stats = _add(stats, f.statistics(block, acts, xhf, h, gy,
+                                                 head_gsq, n))
+            grads = _add(grads, f.rest_grads(acts, xhf, gy))
+            hf.append(h)
+            gy_embed.append(gy["embed"])
+            blocks.append(block)
+            del acts, xhf, h, gy, head_gsq
         if capture:
-            pending[t] = f.statistics(batch, acts, xhf, hf, gy, head_gsq)
+            pending[t] = stats
+        kept = jax.tree.map(lambda *x: _joined(list(x)), *blocks)
         gnorm = {}
         w, v, gnorm["head/w"] = f.head(params["head"]["w"], vel["head"]["w"],
-                                       hf, batch["labels"], pc["head"])
+                                       _joined(hf), kept["labels"],
+                                       pc["head"], n)
         params["head"], vel["head"] = {"w": w}, {"w": v}
         w, v, gnorm["embed/table"] = f.embed(
             params["embed"]["table"], vel["embed"]["table"],
-            batch["tokens"], gy["embed"], pc["embed"])
+            kept["tokens"], _joined(gy_embed), pc["embed"])
         params["embed"], vel["embed"] = {"table": w}, {"table": v}
         rest = ("blocks", "final_norm")
         p, v, g = f.rest({k: params[k] for k in rest},
-                         {k: vel[k] for k in rest}, acts, xhf, gy, pc)
+                         {k: vel[k] for k in rest}, grads, pc)
         params.update(p)
         vel.update(v)
         gnorm.update(g)
-        del acts, xhf, hf, gy, head_gsq
+        del grads, hf, gy_embed, blocks, kept
         out["loss"].append(float(loss))
         if t == 1:
             out["grad1"] = {k: v / o["lr"]

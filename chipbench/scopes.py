@@ -9,14 +9,10 @@ percentage of busy time, or None where the run holds nothing to read.
 
 from __future__ import annotations
 
-import re
-
 from chipbench import trace
 
 # the two jitted step programs, by the name their ops' tf_op starts with
 PROGRAMS = ("jit(train_step)", "jit(fast_step)")
-# a control-flow op's event spans the ops of its body
-_WRAPPER = re.compile(r"(while|cond|conditional)(\.\d+)*$")
 
 
 def under(*needles: str):
@@ -55,7 +51,7 @@ def unscoped_share(ctx):
 
     def leaf(o):
         return (o.scope.startswith(PROGRAMS) and not scoped(o)
-                and not _WRAPPER.match(o.name))
+                and not trace.CONTROL_FLOW.match(o.name))
 
     either = _busy_s(red, lambda o: leaf(o) or scoped(o))
     return 100.0 * (either - _busy_s(red, scoped)) / red.busy_s

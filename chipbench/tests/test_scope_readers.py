@@ -112,3 +112,72 @@ def test_harness_step_is_take_step():
     assert len(ours) == len(theirs)
     for a, b in zip(ours, theirs):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# two chips, busy 0-400 ns each. Chip 0: compute 0-200, an all-reduce
+# 100-150 hidden under it, a reduce-scatter 200-300 alone (under Stage 3),
+# compute 300-400. Chip 1: compute 0-250 and 300-400, an all-gather-done
+# 200-300 whose first half the compute hides, and a while wrapper over all
+# of it (a wrapper hides nothing).
+STAGE3 = "jit(train_step)/shard_map/spngd.stage3.reduce[dense:blk/mlp_up.a]/"
+TWO_CHIPS = trace.Reduction(window=(0, 400), host=[], ops={
+    "/device:TPU:0": [
+        op("fusion.1", "jit(train_step)/spngd.stage1.fwd_bwd/dot:", 0, 200),
+        op("all-reduce.2", "jit(train_step)/shard_map/spngd.stage3.reduce/"
+           "psum:", 100, 150),
+        op("reduce-scatter.3", STAGE3 + "reduce_scatter:", 200, 300),
+        op("fusion.4", "jit(train_step)/spngd.update/add:", 300, 400)],
+    "/device:TPU:1": [
+        op("while.1", "jit(fast_step)/spngd.stage1.fwd_bwd/while:", 0, 400),
+        op("fusion.2", "jit(fast_step)/spngd.stage1.fwd_bwd/dot:", 0, 250),
+        op("all-gather-done.3", "jit(fast_step)/spngd.stage4.gather/"
+           "all_gather:", 200, 300),
+        op("fusion.4", "jit(fast_step)/spngd.update/add:", 300, 400)],
+})
+
+
+def test_collective_shares_on_a_two_chip_trace():
+    """Stage 3: 150 ns on chip 0 (its two collectives), none on chip 1.
+    Exposed: the hidden all-reduce 0, the lone reduce-scatter all 100 ns,
+    the half-hidden all-gather 50 ns: (100 + 50) / 2 of 400 ns busy."""
+    assert TWO_CHIPS.busy_s == pytest.approx(400e-9)
+    assert read("spngd.stage3_share", TWO_CHIPS) == pytest.approx(
+        100.0 * 75 / 400)
+    assert TWO_CHIPS.collective_s() == pytest.approx(125e-9)
+    assert TWO_CHIPS.exposed_collective_s() == pytest.approx(75e-9)
+    assert read("comm.exposed_share", TWO_CHIPS) == pytest.approx(
+        100.0 * 75 / 400)
+    assert read("comm.exposed_share", None) is None
+    assert read("spngd.stage3_share", None) is None
+
+
+@pytest.mark.parametrize("start,end,exposed", [
+    (20, 80, 0.0),          # wholly under the compute
+    (50, 150, 50.0),        # half under it
+    (200, 260, 60.0),       # alone
+])
+def test_exposed_collective_time(start, end, exposed):
+    red = trace.Reduction(window=(0, 300), host=[], ops={"/device:TPU:0": [
+        op("fusion.1", "jit(fast_step)/dot:", 0, 100),
+        op("all-reduce-start.2", "jit(fast_step)/psum:", start, end)]})
+    assert red.exposed_collective_s() == pytest.approx(exposed * 1e-9)
+    assert red.exposed_collective_s() <= red.collective_s()
+
+
+@pytest.mark.parametrize("name,collective", [
+    ("all-reduce.3", True), ("all-gather-start.1", True),
+    ("all-gather-done", True), ("reduce-scatter.2", True),
+    ("collective-permute-done.4", True), ("psum.12", True),
+    ("reduce_scatter.7", True), ("async-collective-done.9", True),
+    ("fusion.3", False), ("dynamic-slice_reduce_fusion.1", False),
+    ("copy-done.2", False), ("while.1", False)])
+def test_collectives_by_op_name(name, collective):
+    """The op names of the collectives in a v5e 2x2 trace of the
+    four-chip cell, XLA's and JAX's, with their async halves."""
+    assert bool(trace.COLLECTIVE.match(name)) is collective
+
+
+def test_collective_readers_read_nothing_on_one_chip(recorded):
+    """The one-chip trace holds no collective and no Stage-3 scope."""
+    assert read("comm.exposed_share", recorded) is None
+    assert read("spngd.stage3_share", recorded) is None

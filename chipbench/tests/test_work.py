@@ -82,7 +82,56 @@ def test_conv_counts_refuse_other_blocks():
         conv.model_flops_per_item(cfg, {})
 
 
+@pytest.mark.parametrize("chips", [1, 4])
+def test_mfu_over_the_peak_of_every_chip(chips):
+    """One chip: the value before ``chips`` was read, bit for bit; four:
+    the same items over four chips' peak."""
+    import types
+    from chipbench import readers
+    cfg = config("qwen1_5_4b-l4")
+    traffic = {"seqs": 2, "accum": 1, "seq_len": 1024}
+    ctx = types.SimpleNamespace(
+        family=lm, config=cfg, traffic=traffic, items=15 * 2048 * chips,
+        window_s=4.418, chips=chips, peaks={"bf16_flops_per_s": 1.97e14})
+    one_chip = 100.0 * lm.model_flops_per_item(cfg, traffic) * ctx.items \
+        / ctx.window_s / ctx.peaks["bf16_flops_per_s"]
+    assert readers.mfu(ctx) == one_chip / chips
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_precond_work_is_one_chips_share(chips):
+    import types
+    from chipbench import readers
+    cfg = config("qwen1_5_4b-l4")
+    ctx = types.SimpleNamespace(family=lm, config=cfg, chips=chips)
+    whole = work.precond_work(lm.dense_sites(cfg), 2048)
+    share = readers.precond_work(ctx)
+    assert (share.flops, share.bytes) == (whole.flops / chips,
+                                          whole.bytes / chips)
+
+
+class _Device:
+    def __init__(self, id, in_use, reserved):
+        self.id = id
+        self._stats = {"peak_bytes_in_use": in_use,
+                       "peak_bytes_reserved": reserved}
+
+    def memory_stats(self):
+        return self._stats
+
+
+@pytest.mark.parametrize("chips,peak", [(1, 10 + 3), (4, 12 + 5)])
+def test_device_peak_bytes_is_the_fullest_chip(monkeypatch, chips, peak):
+    import jax
+    devices = [_Device(0, 10, 3), _Device(1, 12, 5), _Device(2, 11, 1),
+               _Device(3, 9, 0), _Device(4, 100, 0)]
+    monkeypatch.setattr(jax, "devices", lambda: devices)
+    assert harness.device_peak_bytes(chips) == peak
+
+
 @pytest.mark.parametrize("metric,reader", [
+    ("spngd.stage3_share.lm", "spngd.stage3_share.py"),
+    ("comm.exposed_share.lm", "comm.exposed_share.py"),
     ("step.mfu.lm", "step.mfu.py"), ("step.mfu.conv", "step.mfu.py"),
     ("block_precond_roofline.lm", "block_precond_roofline.lm.py"),
     ("setup_s", "setup_s.py")])

@@ -16,7 +16,10 @@ loop and around the whole timed window (``chipbench.window``).
 * time under a scope: the summed duration of ops whose scope path holds
   the scope's name;
 * idle gaps: the stretches inside the window where no op runs, each named
-  by the host annotation that overlaps it most.
+  by the host annotation that overlaps it most;
+* exposed collective time: per chip, the stretches in which a collective
+  op (:data:`COLLECTIVE`, by HLO op name) runs and no other op does,
+  averaged over the chips.
 """
 
 from __future__ import annotations
@@ -31,6 +34,14 @@ import re
 WINDOW = "chipbench.window"
 HOST_PREFIX = "chipbench."
 OP_LINE = "XLA Ops"
+# collectives by HLO op name, each with its async halves: XLA's names,
+# the names JAX gives a shard_map's collectives (psum, reduce_scatter), and
+# the TPU's async collective wrapper (a v5e 2x2 trace holds all of these)
+COLLECTIVE = re.compile(r"(all-reduce|reduce-scatter|all-gather|"
+                        r"collective-permute|psum|reduce_scatter|all_gather|"
+                        r"async-collective)(-start|-done)?(\.\d+)*$")
+# a control-flow op's event spans the ops of its body
+CONTROL_FLOW = re.compile(r"(while|cond|conditional)(\.\d+)*$")
 
 
 @dataclasses.dataclass
@@ -49,6 +60,29 @@ def _union(intervals: list[tuple[int, int]]) -> list[tuple[int, int]]:
         else:
             out.append([s, e])
     return [(s, e) for s, e in out]
+
+
+def _length(intervals: list[tuple[int, int]]) -> int:
+    return sum(e - s for s, e in intervals)
+
+
+def _minus(a: list[tuple[int, int]], b: list[tuple[int, int]]
+           ) -> list[tuple[int, int]]:
+    """The parts of the disjoint sorted intervals ``a`` that no interval
+    of the disjoint sorted ``b`` covers."""
+    out, j = [], 0
+    for s, e in a:
+        while j < len(b) and b[j][1] <= s:
+            j += 1
+        k, cur = j, s
+        while k < len(b) and b[k][0] < e:
+            if b[k][0] > cur:
+                out.append((cur, b[k][0]))
+            cur = max(cur, b[k][1])
+            k += 1
+        if cur < e:
+            out.append((cur, e))
+    return out
 
 
 @dataclasses.dataclass
@@ -82,6 +116,28 @@ class Reduction:
             (o.start, o.end) for o in ops
             if any(n in o.scope for n in needles)]))
             for ops in self.ops.values()]
+        return sum(per) / len(per) * 1e-9
+
+    def collective_s(self) -> float:
+        """Device seconds in which a collective op runs, averaged over the
+        chips."""
+        per = [_length(_union([(o.start, o.end) for o in ops
+                               if COLLECTIVE.match(o.name)]))
+               for ops in self.ops.values()]
+        return sum(per) / len(per) * 1e-9
+
+    def exposed_collective_s(self) -> float:
+        """Device seconds in which a collective op runs and no other op
+        does (control-flow wrappers, which span their bodies, left out),
+        averaged over the chips."""
+        per = []
+        for ops in self.ops.values():
+            comm = _union([(o.start, o.end) for o in ops
+                           if COLLECTIVE.match(o.name)])
+            other = _union([(o.start, o.end) for o in ops
+                            if not COLLECTIVE.match(o.name)
+                            and not CONTROL_FLOW.match(o.name)])
+            per.append(_length(_minus(comm, other)))
         return sum(per) / len(per) * 1e-9
 
     def gaps(self, device: str) -> list[tuple[int, int]]:
